@@ -19,9 +19,10 @@ Backends are named and live in a registry:
     Every hot-path op routed through the hand-written Pallas kernels
     (``kernels.queue_steal.ring_gather``, ``kernels.queue_push.
     ring_scatter`` / ``ring_slice``) — Pallas lowering on TPU, the
-    kernel modules' jnp oracles elsewhere.  Per-call geometry predicates
-    still gate each op (an unsupported geometry silently uses the
-    reference path for that op, as before).
+    kernel modules' jnp oracles elsewhere.  Per-call geometry and
+    payload-dtype predicates still gate each op (an unsupported one runs
+    that op on the reference path, with a one-shot
+    ``BackendFallbackWarning``).
 ``"auto"``
     Resolves the kernel routing ONCE at construction from the queue
     geometry via the kernel modules' predicates
@@ -189,36 +190,56 @@ def item_nbytes(item_spec: Pytree) -> int:
 # ---------------------------------------------------------------------------
 
 
-def kernel_push_available(capacity: int, max_push: int) -> bool:
+def kernel_push_available(capacity: int, max_push: int,
+                          dtype=jnp.int32) -> bool:
     """Whether the Pallas ring-scatter kernel can serve a push of this
-    geometry."""
+    geometry and payload dtype."""
     from repro.kernels.queue_push.kernel import ring_scatter_supported
 
-    return ring_scatter_supported(capacity, max_push)
+    return ring_scatter_supported(capacity, max_push, dtype=dtype)
 
 
-def kernel_pop_available(capacity: int, max_n: int) -> bool:
+def kernel_pop_available(capacity: int, max_n: int,
+                         dtype=jnp.int32) -> bool:
     """Whether the Pallas ring-slice kernel can serve a bulk pop of this
-    geometry."""
+    geometry and payload dtype."""
     from repro.kernels.queue_push.kernel import ring_slice_supported
 
-    return ring_slice_supported(capacity, max_n)
+    return ring_slice_supported(capacity, max_n, dtype=dtype)
 
 
-def kernel_steal_available(capacity: int, max_steal: int) -> bool:
+def kernel_steal_available(capacity: int, max_steal: int,
+                           dtype=jnp.int32) -> bool:
     """Whether the Pallas ring-gather kernel can serve a steal of this
-    geometry."""
+    geometry and payload dtype."""
     from repro.kernels.queue_steal.kernel import ring_gather_supported
 
-    return ring_gather_supported(capacity, max_steal)
+    return ring_gather_supported(capacity, max_steal, dtype=dtype)
 
 
-def kernel_transfer_available(capacity: int, max_steal: int) -> bool:
+def kernel_transfer_available(capacity: int, max_steal: int,
+                              dtype=jnp.int32) -> bool:
     """Whether the Pallas fused ring-transfer kernel can serve the
-    compact superstep's thief-side cut-and-splice of this geometry."""
+    compact superstep's thief-side cut-and-splice of this geometry and
+    payload dtype."""
     from repro.kernels.queue_transfer.kernel import ring_transfer_supported
 
-    return ring_transfer_supported(capacity, max_steal)
+    return ring_transfer_supported(capacity, max_steal, dtype=dtype)
+
+
+def _kernel_routed(op: str, pred, capacity: int, bound: int,
+                   buf: Pytree) -> bool:
+    """Per-call gate of a kernel-routed op: the predicate must admit
+    every payload leaf.  Runs at trace time (static shapes and dtypes);
+    an op it sends to the reference path warns once."""
+    bad = sorted({str(leaf.dtype) for leaf in jax.tree_util.tree_leaves(buf)
+                  if not pred(capacity, bound, leaf.dtype)})
+    if bad:
+        _warn_fallback(
+            ("call", op, capacity, bound, tuple(bad)),
+            f"{op} runs on the reference path: the kernel predicate "
+            f"rejected capacity={capacity}, bound={bound} for {bad}")
+    return not bad
 
 
 # ---------------------------------------------------------------------------
@@ -240,7 +261,8 @@ def _push(q: QueueState, batch: Pytree, n: jnp.ndarray, *,
     bsz = _batch_size(batch)
     n = jnp.minimum(jnp.asarray(n, jnp.int32), jnp.int32(cap) - q.size)
     n = jnp.maximum(n, 0)
-    if kernel and kernel_push_available(cap, bsz):
+    if kernel and _kernel_routed("push", kernel_push_available, cap,
+                                 bsz, q.buf):
         from repro.kernels.queue_push.ops import push_scatter
 
         buf = push_scatter(
@@ -292,7 +314,8 @@ def _pop_bulk(q: QueueState, max_n: int, n: jnp.ndarray, *,
     cap = _capacity(q)
     n = jnp.minimum(jnp.minimum(jnp.asarray(n, jnp.int32), q.size), max_n)
     n = jnp.maximum(n, 0)
-    if kernel and kernel_pop_available(cap, max_n):
+    if kernel and _kernel_routed("pop_bulk", kernel_pop_available, cap,
+                                 max_n, q.buf):
         from repro.kernels.queue_push.ops import pop_slice
 
         batch = pop_slice(
@@ -319,7 +342,8 @@ def _gather_block(q: QueueState, n: jnp.ndarray, max_steal: int,
     claims to).
     """
     cap = _capacity(q)
-    if kernel and kernel_steal_available(cap, max_steal):
+    if kernel and _kernel_routed("steal", kernel_steal_available, cap,
+                                 max_steal, q.buf):
         from repro.kernels.queue_steal.ops import steal_gather
 
         return steal_gather(
@@ -411,7 +435,8 @@ def _transfer(q: QueueState, gathered: Pytree, src_row, n, *,
                     jnp.minimum(jnp.int32(cap) - q.size,
                                 jnp.int32(max_steal)))
     n = jnp.maximum(n, 0)
-    if kernel and kernel_transfer_available(cap, max_steal):
+    if kernel and _kernel_routed("transfer", kernel_transfer_available,
+                                 cap, max_steal, q.buf):
         from repro.kernels.queue_transfer.ops import transfer_splice
 
         buf = transfer_splice(

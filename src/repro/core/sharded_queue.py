@@ -94,8 +94,6 @@ def sharded_superstep(
     ``StealPolicy(backend=...)`` selects the same implementation here as
     everywhere else.
     """
-    from jax.experimental.shard_map import shard_map
-
     axes = (pod_axis, worker_axis) if pod_axis else (worker_axis,)
     spec = P(axes)
 
@@ -122,7 +120,7 @@ def sharded_superstep(
 
     stats_spec = master_ops.RebalanceStats(
         *([P(None)] * len(master_ops.RebalanceStats._fields)))
-    fn = shard_map(
+    fn = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(q_ops.QueueState(buf=spec, lo=spec, size=spec),),
@@ -130,6 +128,6 @@ def sharded_superstep(
             q_ops.QueueState(buf=spec, lo=spec, size=spec),
             stats_spec,
         ),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)

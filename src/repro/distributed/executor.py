@@ -46,14 +46,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import master as master_ops
 from repro.core import ops as bulk_ops
 from repro.runtime import resilience
 from repro.runtime.adaptive import adaptive_update
-from repro.runtime.executor import StealRuntime, WorkerFn, make_lane_step
+from repro.runtime.executor import (LiftedJit, StealRuntime, WorkerFn,
+                                    make_lane_step)
 
 __all__ = ["MeshStealRuntime"]
 
@@ -118,6 +118,19 @@ class MeshStealRuntime(StealRuntime):
                          pod_axis=pod_axis or "pods",
                          queue_sharding=self.sharding, **kwargs)
 
+    # -- host-level lane access ----------------------------------------------
+
+    def lane(self, worker: int) -> bulk_ops.QueueState:
+        """Lane ``worker``'s queue on its own device.  Sliced off the
+        sharded stack it is replicated over the mesh, where a Pallas
+        queue kernel cannot run (Mosaic kernels are not partitioned)."""
+        return jax.device_put(super().lane(worker),
+                              self.mesh.devices.flat[worker])
+
+    def set_lane(self, worker: int, qi: bulk_ops.QueueState) -> None:
+        super().set_lane(worker, jax.device_put(
+            qi, NamedSharding(self.mesh, P())))
+
     # -- the round, shard_mapped --------------------------------------------
 
     def _axes_tuple(self) -> tuple:
@@ -142,11 +155,11 @@ class MeshStealRuntime(StealRuntime):
             q, c, stats = lane_fn(q, c, proportion, ctx)
             return _add_lane(q), _add_lane(c), _add_lane(stats)
 
-        return shard_map(
+        return jax.shard_map(
             local_step, mesh=self.mesh,
             in_specs=(lane, lane, P(), ctx_spec),
             out_specs=(lane, lane, lane),
-            check_rep=False)
+            check_vma=False)
 
     def _fused_round(self, worker_fn: Optional[WorkerFn]) -> Callable:
         """Per-shard ``(q, carry, p) -> (q, carry, p', tele, total)``:
@@ -247,12 +260,12 @@ class MeshStealRuntime(StealRuntime):
 
         tele_spec = {"stats": P(None, entry), "sizes": P(None, entry),
                      "proportion": P(None)}
-        fused = shard_map(
+        fused = jax.shard_map(
             local_fused, mesh=self.mesh,
             in_specs=(lane, lane, P(), ctx_spec),
             out_specs=(lane, lane, P(), tele_spec, P()),
-            check_rep=False)
-        return jax.jit(fused, donate_argnums=self._donate_argnums())
+            check_vma=False)
+        return LiftedJit(fused, self._donate_argnums())
 
     # -- resilience: elastic state shardings ---------------------------------
 

@@ -64,11 +64,9 @@ class DeviceReplicaLane:
         first (the host queues' LIFO discipline)."""
         rt = self._master.runtime
         i = self.replica_id
-        qi = jax.tree_util.tree_map(lambda x: x[i], rt.queues)
-        qi, batch, n = rt.ops.pop_bulk(qi, int(max_wave),
+        qi, batch, n = rt.ops.pop_bulk(rt.lane(i), int(max_wave),
                                        jnp.int32(max_wave))
-        rt.queues = jax.tree_util.tree_map(
-            lambda full, one: full.at[i].set(one), rt.queues, qi)
+        rt.set_lane(i, qi)
         # pop_bulk returns the block oldest-first; reverse for LIFO.
         rids = np.asarray(batch)[: int(n)][::-1]
         wave = [self._master.lookup(int(r)) for r in rids]
@@ -111,6 +109,8 @@ class RuntimeAdmissionMaster:
         :meth:`evict`/:meth:`readmit` can drain and mask lanes live —
         the default; both execution modes arm it, so vmap/mesh parity
         is preserved.
+      backend: queue-op backend of the runtime (default: the policy's,
+        ``"auto"``).
     """
 
     def __init__(self, n_replicas: int,
@@ -122,7 +122,8 @@ class RuntimeAdmissionMaster:
                  mesh=None,
                  item_spec=None,
                  max_pop: Optional[int] = None,
-                 elastic: bool = True):
+                 elastic: bool = True,
+                 backend=None):
         self.policy = policy or StealPolicy(proportion=0.5,
                                             low_watermark=1,
                                             high_watermark=8,
@@ -134,7 +135,8 @@ class RuntimeAdmissionMaster:
             n_replicas, capacity, self.item_spec, execution=execution,
             mesh=mesh, policy=self.policy, adaptive=adaptive,
             adaptive_config=adaptive_config,
-            fault_plan=FaultPlan() if elastic else None, **extra)
+            fault_plan=FaultPlan() if elastic else None, backend=backend,
+            **extra)
         self.replicas = [DeviceReplicaLane(self, i)
                          for i in range(n_replicas)]
         self._requests: Dict[int, object] = {}

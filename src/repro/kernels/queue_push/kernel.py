@@ -11,8 +11,8 @@ mirror image of the steal-side gather (``kernels.queue_steal``):
     ``input_output_aliases`` and the grid visits only the blocks the
     splice touches — cost is O(batch), constant per item and flat in the
     batch size (Fig. 6), never O(capacity).  Each output block straddles
-    at most two aligned batch blocks; the true segment is cut out with
-    one ``dynamic_slice`` at ``block - start % block`` and non-spliced
+    at most two aligned batch blocks; the true segment is cut out at
+    ``block - start % block`` (``ring_tile.cut_rows``) and non-spliced
     rows pass the old ring contents through (read-modify-write of the
     aliased block).
 
@@ -25,7 +25,9 @@ mirror image of the steal-side gather (``kernels.queue_steal``):
     kernel with no host-side cursor arithmetic.
 
 Scalar cursors arrive via ``PrefetchScalarGridSpec`` so the input DMA
-windows align to the dynamic cut before the kernel body runs.
+windows align to the dynamic cut before the kernel body runs.  A batch
+shorter than a tile is padded up to one (``ring_tile.ring_geometry``):
+padded push rows are never live, padded pop rows are sliced off.
 """
 
 from __future__ import annotations
@@ -37,6 +39,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ring_tile import (DEFAULT_BLOCK, cut_rows, narrow,
+                                     ring_geometry, widen)
+
 __all__ = [
     "ring_scatter",
     "ring_scatter_supported",
@@ -45,8 +50,6 @@ __all__ = [
     "DEFAULT_BLOCK",
 ]
 
-DEFAULT_BLOCK = 128
-
 
 # ---------------------------------------------------------------------------
 # ring_scatter: bulk push splice
@@ -54,32 +57,31 @@ DEFAULT_BLOCK = 128
 
 
 def ring_scatter_supported(capacity: int, max_push: int, *,
+                           dtype=jnp.int32,
                            block: int = DEFAULT_BLOCK) -> bool:
-    """Whether :func:`ring_scatter` admits this geometry.  Mirrors the
-    block selection below; additionally the splice span (``max_push``
-    plus one straddle block) must not lap the ring, so every grid step
-    writes a DISTINCT ring block (the in-place splice would otherwise
+    """Whether :func:`ring_scatter` admits this geometry
+    (``ring_tile.ring_geometry`` with ``splice=True``: the splice span
+    plus one straddle block must not lap the ring, so every grid step
+    writes a DISTINCT ring block — the in-place splice would otherwise
     read a block another step already rewrote)."""
-    block = min(block, max_push, capacity)
-    return (block > 0 and capacity % block == 0 and max_push % block == 0
-            and max_push + block <= capacity)
+    return ring_geometry(capacity, max_push, dtype, block=block,
+                         splice=True) is not None
 
 
 def _scatter_kernel(start_ref, n_ref, prev_ref, cur_ref, buf_ref, o_ref, *,
-                    block: int, width: int, max_push: int):
+                    block: int, max_push: int):
     i = pl.program_id(0)
     r = start_ref[0] % block
     n = jnp.minimum(n_ref[0], max_push)
     # Batch rows i*block - r + k, k in [0, block): cut one aligned window
     # out of the two candidate batch blocks.
-    both = jnp.concatenate([prev_ref[...], cur_ref[...]], axis=0)
-    vals = jax.lax.dynamic_slice(both, (block - r, 0), (block, width))
-    off = (i * block - r
-           + jax.lax.broadcasted_iota(jnp.int32, (block, width), 0))
+    vals = cut_rows(widen(prev_ref[...]), widen(cur_ref[...]), block - r)
+    off = i * block - r + jax.lax.broadcasted_iota(jnp.int32, vals.shape, 0)
     live = (off >= 0) & (off < n)
     # Read-modify-write: rows outside the splice keep the old ring
     # contents (the output aliases the ring buffer input).
-    o_ref[...] = jnp.where(live, vals, buf_ref[...])
+    o_ref[...] = narrow(jnp.where(live, vals, widen(buf_ref[...])),
+                        o_ref.dtype)
 
 
 def ring_scatter(buf: jnp.ndarray, batch: jnp.ndarray, start: jnp.ndarray,
@@ -93,13 +95,15 @@ def ring_scatter(buf: jnp.ndarray, batch: jnp.ndarray, start: jnp.ndarray,
     """
     cap, width = buf.shape
     max_push = batch.shape[0]
-    block = min(block, max_push, cap)
-    assert ring_scatter_supported(cap, max_push, block=block)
+    geo = ring_geometry(cap, max_push, buf.dtype, block=block, splice=True)
+    assert geo is not None, (cap, max_push, buf.dtype)
+    block, span = geo
+    if span != max_push:
+        batch = jnp.pad(batch, ((0, span - max_push), (0, 0)))
     nb = cap // block
-    bb = max_push // block
+    bb = span // block
 
-    kern = functools.partial(_scatter_kernel, block=block, width=width,
-                             max_push=max_push)
+    kern = functools.partial(_scatter_kernel, block=block, max_push=max_push)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         # bb batch blocks land on bb + 1 ring blocks (dynamic straddle).
@@ -131,25 +135,22 @@ def ring_scatter(buf: jnp.ndarray, batch: jnp.ndarray, start: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
-def ring_slice_supported(capacity: int, max_n: int, *,
+def ring_slice_supported(capacity: int, max_n: int, *, dtype=jnp.int32,
                          block: int = DEFAULT_BLOCK) -> bool:
-    """Whether :func:`ring_slice` admits this geometry (same tiling rule
-    as the steal-side gather: ring and transfer buffer must be whole
-    numbers of possibly-shrunken blocks)."""
-    block = min(block, max_n, capacity)
-    return block > 0 and capacity % block == 0 and max_n % block == 0
+    """Whether :func:`ring_slice` admits this geometry (the steal-side
+    gather's rule, ``ring_tile.ring_geometry``)."""
+    return ring_geometry(capacity, max_n, dtype, block=block) is not None
 
 
 def _slice_kernel(lo_ref, size_ref, n_ref, a_ref, b_ref, o_ref, *,
-                  block: int, width: int, cap: int):
+                  block: int, cap: int):
     i = pl.program_id(0)
     n = n_ref[0]
     start = (lo_ref[0] + size_ref[0] - n) % cap
-    r = start % block
-    both = jnp.concatenate([a_ref[...], b_ref[...]], axis=0)
-    seg = jax.lax.dynamic_slice(both, (r, 0), (block, width))
-    row = i * block + jax.lax.broadcasted_iota(jnp.int32, (block, width), 0)
-    o_ref[...] = jnp.where(row < n, seg, jnp.zeros_like(seg))
+    seg = cut_rows(widen(a_ref[...]), widen(b_ref[...]), start % block)
+    row = i * block + jax.lax.broadcasted_iota(jnp.int32, seg.shape, 0)
+    o_ref[...] = narrow(jnp.where(row < n, seg, jnp.zeros_like(seg)),
+                        o_ref.dtype)
 
 
 def ring_slice(buf: jnp.ndarray, lo: jnp.ndarray, size: jnp.ndarray,
@@ -159,19 +160,18 @@ def ring_slice(buf: jnp.ndarray, lo: jnp.ndarray, size: jnp.ndarray,
     order (oldest of the block first), rows >= ``n`` zeroed.  ``n`` must
     already be clamped to ``size``."""
     cap, width = buf.shape
-    block = min(block, max_n, cap)
-    assert ring_slice_supported(cap, max_n, block=block)
+    geo = ring_geometry(cap, max_n, buf.dtype, block=block)
+    assert geo is not None, (cap, max_n, buf.dtype)
+    block, span = geo
     nb = cap // block
-    n_out = max_n // block
 
     def _start_block(lo, size, n):
         return ((lo[0] + size[0] - n[0]) % cap) // block
 
-    kern = functools.partial(_slice_kernel, block=block, width=width,
-                             cap=cap)
+    kern = functools.partial(_slice_kernel, block=block, cap=cap)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(n_out,),
+        grid=(span // block,),
         in_specs=[
             pl.BlockSpec((block, width),
                          lambda i, lo, sz, n:
@@ -182,11 +182,12 @@ def ring_slice(buf: jnp.ndarray, lo: jnp.ndarray, size: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((block, width), lambda i, lo, sz, n: (i, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((max_n, width), buf.dtype),
+        out_shape=jax.ShapeDtypeStruct((span, width), buf.dtype),
         interpret=interpret,
     )(jnp.asarray(lo, jnp.int32).reshape(1),
       jnp.asarray(size, jnp.int32).reshape(1),
       jnp.asarray(n, jnp.int32).reshape(1), buf, buf)
+    return out if span == max_n else out[:max_n]

@@ -18,7 +18,7 @@ ONE kernel:
 * the splice start ``head = (lo + size) % cap`` is DYNAMIC too, exactly
   as in ``kernels.queue_push.ring_scatter``: each touched ring block
   straddles at most two aligned gathered blocks, the true segment is cut
-  with one ``dynamic_slice`` at ``block - head % block``, and rows
+  at ``block - head % block`` (``ring_tile.cut_rows``), and rows
   outside ``[0, n)`` pass the old ring contents through (read-modify-
   write of the aliased block — the ring buffer is updated IN PLACE via
   ``input_output_aliases``);
@@ -27,7 +27,9 @@ ONE kernel:
   O(W * max_steal).
 
 Structurally this is ``ring_scatter`` generalized with a dynamic source
-offset into a source buffer W times larger than the splice span.
+offset into a source buffer W times larger than the splice span.  A
+span shorter than a tile is padded up to one per lane window
+(``ring_tile.ring_geometry``); padded rows are never live.
 """
 
 from __future__ import annotations
@@ -39,41 +41,38 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["ring_transfer", "ring_transfer_supported", "DEFAULT_BLOCK"]
+from repro.kernels.ring_tile import (DEFAULT_BLOCK, cut_rows, narrow,
+                                     ring_geometry, widen)
 
-DEFAULT_BLOCK = 128
+__all__ = ["ring_transfer", "ring_transfer_supported", "DEFAULT_BLOCK"]
 
 
 def ring_transfer_supported(capacity: int, max_steal: int, *,
+                            dtype=jnp.int32,
                             block: int = DEFAULT_BLOCK) -> bool:
     """Whether :func:`ring_transfer` admits this geometry.  Same rule as
-    the push-side ring-scatter: ring and transfer span must be whole
-    numbers of (possibly shrunken) blocks, and the splice span
-    (``max_steal`` plus one straddle block) must not lap the ring so
-    every grid step writes a DISTINCT ring block.  The gathered source
-    is ``n_lanes * max_steal`` rows, automatically block-aligned when
-    ``max_steal`` is."""
-    block = min(block, max_steal, capacity)
-    return (block > 0 and capacity % block == 0 and max_steal % block == 0
-            and max_steal + block <= capacity)
+    the push-side ring-scatter (``ring_tile.ring_geometry`` with
+    ``splice=True``); the gathered source is ``n_lanes`` windows of the
+    padded span, so it is block-aligned whenever the span is."""
+    return ring_geometry(capacity, max_steal, dtype, block=block,
+                         splice=True) is not None
 
 
 def _transfer_kernel(c_ref, prev_ref, cur_ref, buf_ref, o_ref, *,
-                     block: int, width: int, max_steal: int):
+                     block: int, max_steal: int):
     i = pl.program_id(0)
     head, n = c_ref[0], c_ref[2]
     r = head % block
     n = jnp.minimum(n, max_steal)
     # Gathered rows src_start + i*block - r + k, k in [0, block): cut one
     # aligned window out of the two candidate gathered blocks.
-    both = jnp.concatenate([prev_ref[...], cur_ref[...]], axis=0)
-    vals = jax.lax.dynamic_slice(both, (block - r, 0), (block, width))
-    off = (i * block - r
-           + jax.lax.broadcasted_iota(jnp.int32, (block, width), 0))
+    vals = cut_rows(widen(prev_ref[...]), widen(cur_ref[...]), block - r)
+    off = i * block - r + jax.lax.broadcasted_iota(jnp.int32, vals.shape, 0)
     live = (off >= 0) & (off < n)
     # Read-modify-write: rows outside the splice keep the old ring
     # contents (the output aliases the ring buffer input).
-    o_ref[...] = jnp.where(live, vals, buf_ref[...])
+    o_ref[...] = narrow(jnp.where(live, vals, widen(buf_ref[...])),
+                        o_ref.dtype)
 
 
 def ring_transfer(buf: jnp.ndarray, gathered: jnp.ndarray,
@@ -92,15 +91,22 @@ def ring_transfer(buf: jnp.ndarray, gathered: jnp.ndarray,
     to the output (in-place splice).
     """
     cap, width = buf.shape
-    srows = gathered.shape[0]
-    block = min(block, max_steal, cap)
-    assert ring_transfer_supported(cap, max_steal, block=block)
-    assert srows % block == 0
+    geo = ring_geometry(cap, max_steal, buf.dtype, block=block, splice=True)
+    assert geo is not None, (cap, max_steal, buf.dtype)
+    assert gathered.shape[0] % max_steal == 0
+    block, span = geo
+    src_start = jnp.asarray(src_start, jnp.int32)
+    if span != max_steal:
+        lanes = gathered.shape[0] // max_steal
+        gathered = jnp.pad(gathered.reshape(lanes, max_steal, width),
+                           ((0, 0), (0, span - max_steal), (0, 0)))
+        gathered = gathered.reshape(lanes * span, width)
+        src_start = src_start // max_steal * span
     nb = cap // block
-    sb = srows // block
-    bb = max_steal // block
+    sb = gathered.shape[0] // block
+    bb = span // block
 
-    kern = functools.partial(_transfer_kernel, block=block, width=width,
+    kern = functools.partial(_transfer_kernel, block=block,
                              max_steal=max_steal)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -118,8 +124,7 @@ def ring_transfer(buf: jnp.ndarray, gathered: jnp.ndarray,
         out_specs=pl.BlockSpec((block, width),
                                lambda i, c: ((c[0] // block + i) % nb, 0)),
     )
-    scalars = jnp.stack([jnp.asarray(head, jnp.int32),
-                         jnp.asarray(src_start, jnp.int32),
+    scalars = jnp.stack([jnp.asarray(head, jnp.int32), src_start,
                          jnp.asarray(n, jnp.int32)])
     return pl.pallas_call(
         kern,

@@ -243,7 +243,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     parallel = ParallelConfig(pod_axis="pod" if multi_pod else None)
 
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         # Pass 1 — production (scanned) program: proves sharding coherence
         # and gives the honest memory_analysis.
         layers_mod.set_scan_unroll(False)

@@ -64,4 +64,5 @@ def make_production_mesh(*, multi_pod: bool = False):
 
         return jax.sharding.Mesh(
             np.asarray(devices[:n]).reshape(shape), axes)
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -37,25 +37,10 @@ ICI_BW = 50e9              # bytes/s per link
 HW = {"peak_flops": PEAK_FLOPS, "hbm_bw": HBM_BW, "ici_bw": ICI_BW}
 
 def normalize_cost_analysis(ca) -> Dict[str, float]:
-    """Normalize ``Compiled.cost_analysis()`` across jax versions.
-
-    jax <= 0.4.30 returns a flat dict, jax 0.4.31+ (incl. 0.4.37) returns
-    a *list* with one dict per program, and either may be ``None``/empty.
-    Returns one flat dict (numeric values summed across programs) so
-    callers can ``.get("flops", 0)`` unconditionally.
-    """
-    if ca is None:
-        return {}
-    if isinstance(ca, dict):
-        return dict(ca)
-    out: Dict[str, float] = {}
-    for entry in ca:  # list/tuple of per-program dicts
-        for k, v in (entry or {}).items():
-            if isinstance(v, (int, float)) and k in out:
-                out[k] += v
-            else:
-                out[k] = v
-    return out
+    """``Compiled.cost_analysis()`` as a plain dict: the installed jax
+    returns one flat dict, or ``None`` where the backend has no cost
+    model, so callers can ``.get("flops", 0)`` unconditionally."""
+    return dict(ca or {})
 
 
 _DTYPE_BYTES = {

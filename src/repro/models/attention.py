@@ -294,7 +294,6 @@ def decode_attention_shardmap(p, x, cache_k, cache_v, pos, cfg: AttnConfig,
     Wire bytes per layer-step: O(B * H * hd) for the merge — independent
     of the cache length.  Falls back to None when no mesh is active.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m = _active_mesh()
@@ -376,11 +375,11 @@ def decode_attention_shardmap(p, x, cache_k, cache_v, pos, cfg: AttnConfig,
         pspec["q_norm"] = P(None)
         pspec["k_norm"] = P(None)
     cache_spec = P(bspec, tp, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=m,
         in_specs=(pspec, P(bspec, None, None), cache_spec, cache_spec, P()),
         out_specs=(P(bspec, None, None), cache_spec, cache_spec),
-        check_rep=False)
+        check_vma=False)
     return fn(p, x, cache_k, cache_v, pos)
 
 

@@ -53,14 +53,13 @@ def pscan(body, init, xs, length=None):
 
 
 def _active_mesh():
-    """The mesh installed by ``with mesh:`` (pjit's resource env), if any."""
-    try:
-        from jax._src import mesh as mesh_lib
-
-        m = mesh_lib.thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:  # pragma: no cover - accessor moved
-        return None
+    """The (abstract) mesh installed by ``with jax.set_mesh(mesh):``, if
+    any; readable inside ``jax.jit``, where shardings and ``shard_map``
+    resolve against it.  None inside a ``shard_map`` body, whose axes
+    are Manual: there the model code runs per shard, unconstrained."""
+    m = jax.sharding.get_abstract_mesh()
+    auto = all(t == jax.sharding.AxisType.Auto for t in m.axis_types)
+    return None if m.empty or not auto else m
 
 
 def shard(x: jnp.ndarray, *spec) -> jnp.ndarray:

@@ -237,7 +237,6 @@ def moe_apply_ep_shardmap(p: Pytree, x: jnp.ndarray, *, top_k: int,
 
     Returns None when unavailable (no mesh, or E % tp != 0).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.models.layers import _active_mesh
 
@@ -308,10 +307,10 @@ def moe_apply_ep_shardmap(p: Pytree, x: jnp.ndarray, *, top_k: int,
         "w_up": P(sh.tp, None, None),
         "w_down": P(sh.tp, None, None),
     }
-    fn = shard_map(local_fn, mesh=mesh,
-                   in_specs=(pspec, P(dp_axes or None, None, None)),
-                   out_specs=P(dp_axes or None, None, None),
-                   check_rep=False)
+    fn = jax.shard_map(local_fn, mesh=mesh,
+                       in_specs=(pspec, P(dp_axes or None, None, None)),
+                       out_specs=P(dp_axes or None, None, None),
+                       check_vma=False)
     return fn(p, x.astype(compute_dtype))
 
 

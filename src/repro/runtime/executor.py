@@ -65,7 +65,52 @@ Pytree = Any
 WorkerFn = Callable[[bulk_ops.QueueState, Pytree],
                     Tuple[bulk_ops.QueueState, Pytree]]
 
-__all__ = ["StealRuntime", "make_lane_step"]
+__all__ = ["StealRuntime", "LiftedJit", "make_lane_step"]
+
+
+class LiftedJit:
+    """``jax.jit(fn, donate_argnums=...)``, except that the arrays ``fn``
+    closes over (a decode body's model parameters, say) reach the
+    compiled program as arguments instead of being embedded in it as
+    constants.  A program that embeds gigabytes of weights cannot be
+    compiled.  Each new signature of the arguments is traced once to a
+    jaxpr, whose constants are then passed on every call."""
+
+    def __init__(self, fn: Callable, donate_argnums: tuple = ()):
+        self._fn = fn
+        self._traced: Dict[Any, Tuple[Any, Any]] = {}
+        self._jit = jax.jit(self._eval, static_argnums=(0,),
+                            donate_argnums=tuple(i + 2
+                                                 for i in donate_argnums))
+
+    def _eval(self, key, consts, *args):
+        jaxpr, out_tree = self._traced[key][0]
+        out = jax.core.eval_jaxpr(jaxpr, consts,
+                                  *jax.tree_util.tree_leaves(args))
+        return jax.tree_util.tree_unflatten(out_tree, out)
+
+    def _key(self, args):
+        leaves, tree = jax.tree_util.tree_flatten(args)
+        key = (tree, tuple(jax.typeof(x) for x in leaves))
+        if key not in self._traced:
+            closed, shape = jax.make_jaxpr(self._fn, return_shape=True)(
+                *args)
+            self._traced[key] = ((closed.jaxpr,
+                                  jax.tree_util.tree_structure(shape)),
+                                 closed.consts)
+        return key
+
+    def __call__(self, *args):
+        key = self._key(args)
+        return self._jit(key, self._traced[key][1], *args)
+
+    def lower(self, *args) -> jax.stages.Lowered:
+        """The program a call with ``args`` runs, for inspection."""
+        key = self._key(args)
+        return self._jit.lower(key, self._traced[key][1], *args)
+
+    def _cache_size(self) -> int:
+        return self._jit._cache_size()
 
 
 def make_lane_step(policy: StealPolicy, ops: bulk_ops.BulkOps,
@@ -279,12 +324,19 @@ class StealRuntime:
 
     # -- host-side seeding / draining ---------------------------------------
 
-    def push(self, worker: int, batch: Pytree, n: int) -> int:
-        """Owner-side bulk push into one lane (host-level seeding)."""
-        qi = jax.tree_util.tree_map(lambda x: x[worker], self.queues)
-        qi, pushed = self.ops.push(qi, batch, jnp.int32(n))
+    def lane(self, worker: int) -> bulk_ops.QueueState:
+        """Lane ``worker``'s queue, for a host-level op (``ops.push``,
+        ``ops.pop_bulk``...) whose result goes back by :meth:`set_lane`."""
+        return jax.tree_util.tree_map(lambda x: x[worker], self.queues)
+
+    def set_lane(self, worker: int, qi: bulk_ops.QueueState) -> None:
         self.queues = jax.tree_util.tree_map(
             lambda full, one: full.at[worker].set(one), self.queues, qi)
+
+    def push(self, worker: int, batch: Pytree, n: int) -> int:
+        """Owner-side bulk push into one lane (host-level seeding)."""
+        qi, pushed = self.ops.push(self.lane(worker), batch, jnp.int32(n))
+        self.set_lane(worker, qi)
         return int(pushed)
 
     def drain(self) -> list:
@@ -292,15 +344,14 @@ class StealRuntime:
         a list of per-lane item lists, newest-first per lane."""
         out = []
         for i in range(self.n_workers):
-            qi = jax.tree_util.tree_map(lambda x: x[i], self.queues)
+            qi = self.lane(i)
             lane = []
             while int(qi.size) > 0:
                 qi, item, valid = self.ops.pop(qi)
                 assert bool(valid)
                 lane.append(jax.tree_util.tree_map(np.asarray, item))
             out.append(lane)
-            self.queues = jax.tree_util.tree_map(
-                lambda full, one: full.at[i].set(one), self.queues, qi)
+            self.set_lane(i, qi)
         return out
 
     # -- resilience: live faults, stragglers ---------------------------------
@@ -603,8 +654,7 @@ class StealRuntime:
         return () if jax.default_backend() == "cpu" else (0,)
 
     def _compile(self, worker_fn: Optional[WorkerFn]) -> Callable:
-        return jax.jit(self._make_step(worker_fn),
-                       donate_argnums=self._donate_argnums())
+        return LiftedJit(self._make_step(worker_fn), self._donate_argnums())
 
     def _compile_fused(self, worker_fn: Optional[WorkerFn], k: int,
                        until_drained: bool = False) -> Callable:
@@ -642,7 +692,7 @@ class StealRuntime:
                     body, (qs, carry, p0, ctx0), None, length=k)
                 return qs, carry, p, tele, jnp.int32(k)
 
-            return jax.jit(fused, donate_argnums=self._donate_argnums())
+            return LiftedJit(fused, self._donate_argnums())
 
         def fused(qs, carry, p0, ctx0):
             tele_sds = jax.eval_shape(
@@ -667,7 +717,7 @@ class StealRuntime:
                 cond, body, (qs, carry, p0, ctx0, jnp.int32(0), tele0))
             return qs, carry, p, tele, r
 
-        return jax.jit(fused, donate_argnums=self._donate_argnums())
+        return LiftedJit(fused, self._donate_argnums())
 
     # -- observability: the phase probe --------------------------------------
 
@@ -960,6 +1010,21 @@ class StealRuntime:
             stats = jax.tree_util.tree_map(lambda x: x[:rounds], stats)
             return carry, stats, rounds
         return carry, stats
+
+    def lower(self, worker_fn: Optional[WorkerFn] = None,
+              carry: Optional[Pytree] = None, *,
+              fused: int = 1) -> jax.stages.Lowered:
+        """The program :meth:`run` dispatches (one round, or a
+        ``fused``-round early-exit block), lowered for the current
+        state without running it — e.g. to check that the queue kernels
+        are in it (``.compile().as_text()``)."""
+        if carry is None:
+            carry = jnp.zeros((self.n_workers,), jnp.int32)
+        args = (self.queues, carry, jnp.float32(self.proportion),
+                self._ctx(self.rounds_run))
+        if fused > 1:
+            return self._compile_fused(worker_fn, fused, True).lower(*args)
+        return self._compile(worker_fn).lower(*args)
 
     def run(self, worker_fn: Optional[WorkerFn] = None,
             carry: Optional[Pytree] = None, *,

@@ -409,7 +409,7 @@ class DecodeCluster:
                 self.n_lanes, policy=trace_pol, adaptive=False,
                 execution=execution, capacity=capacity, mesh=mesh,
                 item_spec=spec, max_pop=self.policy.n_slots,
-                elastic=False)
+                elastic=False, backend=backend)
             self.runtime = self.master.runtime
         # Token-load-watermarked proportion servo (decode's analogue of
         # the item-count controller): its output is injected into the

@@ -175,6 +175,13 @@ _CHECKS = textwrap.dedent("""
         rt = launch_runtime(8, 64, SPEC, execution="mesh", mesh=mesh,
                             pod_size=4)
         assert rt.pod_size == 4 and rt.n_workers == 8
+        # a host-level op on one lane runs on that lane's own device (a
+        # Pallas queue kernel cannot be partitioned over the mesh)
+        for w in (0, 5):
+            leaf = rt.lane(w).size
+            assert leaf.sharding.device_set == {mesh.devices.flat[w]}
+        assert rt.push(5, jnp.arange(3, dtype=jnp.int32), 3) == 3
+        assert [int(x) for x in rt.sizes()] == [0] * 5 + [3, 0, 0]
         print("LAUNCH-OK")
 
     def solver_checks():

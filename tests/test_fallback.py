@@ -66,3 +66,25 @@ def test_explicit_backend_request_is_silent(monkeypatch):
         warnings.simplefilter("always")
         bulk_ops.make_ops("reference")
     assert _fallback_msgs(rec) == []
+
+
+def test_per_call_payload_rejection_warns_once():
+    """A kernel-routed op whose payload dtype the kernels cannot move
+    (float16) runs on the reference path, with the same results, and says
+    so once."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    pallas, ref = bulk_ops.make_ops("pallas"), bulk_ops.make_ops("reference")
+    q = bulk_ops.make_queue(64, jnp.zeros((2,), jnp.float16))
+    batch = jnp.ones((8, 2), jnp.float16)
+    with warnings.catch_warnings(record=True) as rec:
+        warnings.simplefilter("always")
+        qp, _ = pallas.push(q, batch, 5)
+        pallas.push(q, batch, 5)
+    qr, _ = ref.push(q, batch, 5)
+    np.testing.assert_array_equal(np.asarray(qp.buf), np.asarray(qr.buf))
+    msgs = _fallback_msgs(rec)
+    assert len(msgs) == 1
+    assert "push runs on the reference path" in msgs[0]
+    assert "float16" in msgs[0]

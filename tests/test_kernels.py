@@ -61,6 +61,12 @@ STEAL_CASES = [
     (256, 4, 256, 255, 256, jnp.int32),       # full wrap, int payload
     (256, 4, 128, 13, 0, jnp.float32),        # empty steal
     (256, 128, 256, 100, 200, jnp.bfloat16),
+    # batches shorter than a tile are padded to one; wrap past the end
+    (512, 1, 1, 511, 1, jnp.int32),
+    (512, 1, 4, 510, 4, jnp.int32),
+    (512, 3, 8, 509, 8, jnp.int32),
+    (256, 2, 4, 254, 3, jnp.bfloat16),
+    (256, 8, 8, 250, 8, jnp.bfloat16),
 ]
 
 
@@ -87,6 +93,11 @@ TRANSFER_CASES = [
     (256, 4, 4, 64, 255, 2, 64, jnp.int32),       # int payload, wrap
     (256, 4, 4, 64, 13, 1, 0, jnp.float32),       # empty transfer
     (256, 128, 2, 128, 100, 1, 77, jnp.bfloat16),
+    # spans shorter than a tile are padded to one; wrap past the end
+    (64, 1, 4, 1, 63, 3, 1, jnp.int32),
+    (64, 20, 4, 4, 62, 2, 4, jnp.int32),
+    (64, 3, 2, 8, 60, 1, 8, jnp.float32),
+    (64, 2, 4, 4, 61, 0, 3, jnp.bfloat16),
 ]
 
 

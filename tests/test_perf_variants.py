@@ -29,7 +29,8 @@ _EP_SCRIPT = textwrap.dedent("""
     from repro.models import moe as moe_mod
     from repro.models.layers import ShardPlan
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     sh = ShardPlan(dp=("data",), tp="model", fsdp="data")
     E, D, F, k = 8, 64, 128, 2
     key = jax.random.PRNGKey(0)
@@ -43,7 +44,7 @@ _EP_SCRIPT = textwrap.dedent("""
     x = jax.random.normal(ks[4], (4, 16, D))
     kw = dict(top_k=k, n_experts=E, capacity_factor=2.0, sh=sh,
               compute_dtype=jnp.float32, bulk_steal=True)
-    with mesh:
+    with jax.set_mesh(mesh):
         base = jax.jit(lambda p, x: moe_mod.moe_apply(p, x, impl="gspmd", **kw))(p, x)
         opt = jax.jit(lambda p, x: moe_mod.moe_apply(p, x, impl="ep_shardmap", **kw))(p, x)
     np.testing.assert_allclose(np.asarray(base), np.asarray(opt),
@@ -62,7 +63,8 @@ _FD_SCRIPT = textwrap.dedent("""
     from repro.models import build_model
     import repro.models.transformer as tmod
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     par = ParallelConfig()
     base_cfg = configs.reduced(configs.get("llama3.2-1b"))
     tmod._SEQ_SHARD_MIN = 16   # force the seq-sharded decode path
@@ -74,7 +76,7 @@ _FD_SCRIPT = textwrap.dedent("""
         params = model.init(jax.random.PRNGKey(0))
         toks = jax.random.randint(jax.random.PRNGKey(1), (16, 32), 1,
                                   cfg.vocab_size, jnp.int32)
-        with mesh:
+        with jax.set_mesh(mesh):
             logits, cache = jax.jit(model.prefill)(params, toks)
             cache = model.grow_cache(cache, 40)
             lg, cache = jax.jit(model.decode_step)(params, cache,

@@ -244,6 +244,11 @@ SCATTER_CASES = [
     (256, 4, 128, 255, 128),                      # full wrap from last row
     (1024, 16, 512, 3 * DEFAULT_BLOCK + 63, 511),
     (64, 3, 32, 33, 32),                          # shrunken block (32)
+    # batches shorter than a tile are padded to one block of 8 rows
+    (64, 1, 1, 63, 1),                            # one item at the last row
+    (64, 20, 4, 62, 4),                           # wraps past cap
+    (64, 2, 8, 57, 8),
+    (64, 2, 8, 3, 0),
 ]
 
 
@@ -273,6 +278,11 @@ SLICE_CASES = [
     (256, 4, 256, 255, 200, 129),                      # wraps from last row
     (1024, 16, 256, 3 * DEFAULT_BLOCK + 63, 900, 255),
     (64, 3, 32, 61, 40, 32),                           # shrunken block
+    # pops shorter than a tile: padded to one block, padding sliced off
+    (64, 1, 1, 63, 1, 1),
+    (64, 20, 4, 61, 5, 4),                             # wraps past cap
+    (64, 2, 8, 60, 20, 8),
+    (64, 2, 4, 10, 3, 2),
 ]
 
 
@@ -312,6 +322,57 @@ def test_push_pop_pallas_backend_matches_reference(lo, size, n_push, n_pop):
     assert int(q1.size) == int(q2.size)
     for k in ("a", "b"):
         np.testing.assert_array_equal(np.asarray(b1[k]), np.asarray(b2[k]))
+
+
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.bfloat16])
+@pytest.mark.parametrize("rows,start", [(1, 63), (4, 61), (8, 59)])
+def test_ring_push_pop_small_batches_roundtrip(rows, start, dtype):
+    """A push of 1, 4 or 8 rows that wraps the ring, then a pop of the
+    same rows, through the interpret-mode kernels: both equal the
+    oracles bit for bit, and the pop returns the pushed rows."""
+    cap, width = 64, 3
+    key = jax.random.PRNGKey(rows)
+    buf = jax.random.normal(key, (cap, width), jnp.float32).astype(dtype)
+    batch = jax.random.normal(jax.random.fold_in(key, 1), (rows, width),
+                              jnp.float32).astype(dtype)
+    pushed = ring_scatter(buf, batch, jnp.int32(start), jnp.int32(rows),
+                          interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(pushed), np.asarray(ring_scatter_ref(buf, batch, start,
+                                                        rows)))
+    lo, size = start - 5, rows + 5
+    popped = ring_slice(pushed, jnp.int32(lo), jnp.int32(size),
+                        jnp.int32(rows), rows, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(popped),
+        np.asarray(ring_slice_ref(pushed, lo, size, rows, rows)))
+    np.testing.assert_array_equal(np.asarray(popped), np.asarray(batch))
+
+
+def test_kernel_predicates_state_the_tiling_rule():
+    """Block rows are a multiple of 8 or the whole array; a bound shorter
+    than that is padded to one block; payloads are 32-bit or 16-bit
+    (not float16).  The TPU compile tests check the rule itself against
+    the compiler."""
+    from repro.kernels.ring_tile import (block_rows_ok, ring_geometry,
+                                         supported_dtype)
+
+    assert block_rows_ok(8, 16384) and block_rows_ok(24, 48)
+    assert block_rows_ok(4, 4)                          # whole array
+    assert not block_rows_ok(4, 16384) and not block_rows_ok(12, 24)
+    assert ring_geometry(16384, 1) == (8, 8)            # padded batch
+    assert ring_geometry(4096, 4) == (8, 8)
+    assert ring_geometry(16384, 1024) == (128, 1024)
+    assert ring_geometry(128, 20) is None               # 24-row block
+    assert ring_geometry(8, 1, splice=True) is None     # would lap
+    assert supported_dtype(jnp.bfloat16) and supported_dtype(jnp.uint32)
+    assert not supported_dtype(jnp.float16)
+    assert not supported_dtype(jnp.int8)
+    assert bulk_ops.kernel_pop_available(4096, 4)
+    assert bulk_ops.kernel_push_available(16384, 1)
+    assert bulk_ops.kernel_steal_available(4096, 256, jnp.bfloat16)
+    assert not bulk_ops.kernel_steal_available(4096, 256, jnp.float16)
+    assert not bulk_ops.kernel_transfer_available(4096, 256, jnp.int8)
 
 
 def test_kernel_push_pop_available_geometry():

@@ -74,7 +74,8 @@ _CHECKS = textwrap.dedent("""
 
     def run_checks():
         assert jax.device_count() >= 8, jax.device_count()
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
 
         for exchange in ("compact", "dense"):
             pol = StealPolicy(proportion=0.5, low_watermark=2,
@@ -118,7 +119,9 @@ _CHECKS = textwrap.dedent("""
                                       np.asarray(qs_b.size))
 
         # hierarchical over a (2 pods x 4 workers) mesh
-        mesh2 = jax.make_mesh((2, 4), ("pods", "data"))
+        mesh2 = jax.make_mesh(
+            (2, 4), ("pods", "data"),
+            axis_types=(jax.sharding.AxisType.Auto,) * 2)
         ids_before = totals(seed())
         qs = seed()
         step_h = sharded_superstep(mesh2, pol, worker_axis="data",

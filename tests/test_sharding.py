@@ -23,7 +23,8 @@ _SCRIPT = textwrap.dedent("""
     from repro.train.optimizer import AdamWConfig, adamw_init, opt_state_specs
     from repro.train.trainer import make_train_step
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     arch = os.environ["ARCH"]
     cfg = configs.reduced(configs.get(arch))
     par = ParallelConfig()
@@ -40,7 +41,7 @@ _SCRIPT = textwrap.dedent("""
     params_sds = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     opt_sds = jax.eval_shape(adamw_init, params_sds)
     step = make_train_step(model, AdamWConfig())
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             step,
             in_shardings=(ns(model.param_specs()),
