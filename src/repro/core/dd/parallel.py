@@ -44,6 +44,7 @@ from repro.core.dd.diagram import NEG
 from repro.core.dd.knapsack import Knapsack
 from repro.core.ops import BulkOps, QueueState
 from repro.core.policy import StealPolicy
+from repro.obs import spans
 
 __all__ = ["parallel_solve"]
 
@@ -130,36 +131,43 @@ def parallel_solve(inst: Knapsack, *, n_workers: int = 8,
     """
     from repro.distributed.launch import launch_runtime
 
-    policy = policy or StealPolicy(proportion=0.5, high_watermark=4,
-                                   low_watermark=0,
-                                   max_steal=min(capacity, 1024))
-    w = jnp.asarray(inst.weights, jnp.int32)
-    p = jnp.asarray(inst.profits, jnp.int32)
+    with spans.span("solve:call"):
+        spans.count("solve.calls")
+        with spans.span("solve:build"):
+            policy = policy or StealPolicy(proportion=0.5, high_watermark=4,
+                                           low_watermark=0,
+                                           max_steal=min(capacity, 1024))
+            w = jnp.asarray(inst.weights, jnp.int32)
+            p = jnp.asarray(inst.profits, jnp.int32)
 
-    runtime = launch_runtime(n_workers, capacity, _item_spec(),
-                             execution=execution, policy=policy,
-                             adaptive=adaptive, backend=backend,
-                             max_pop=batch, axis_name=AXIS)
-    # seed: root subproblem on worker 0
-    runtime.push(0, {"layer": jnp.zeros((1,), jnp.int32),
-                     "state": jnp.full((1,), inst.capacity, jnp.int32),
-                     "value": jnp.zeros((1,), jnp.int32)}, 1)
+            runtime = launch_runtime(n_workers, capacity, _item_spec(),
+                                     execution=execution, policy=policy,
+                                     adaptive=adaptive, backend=backend,
+                                     max_pop=batch, axis_name=AXIS)
+            # seed: root subproblem on worker 0
+            runtime.push(0, {"layer": jnp.zeros((1,), jnp.int32),
+                             "state": jnp.full((1,), inst.capacity,
+                                               jnp.int32),
+                             "value": jnp.zeros((1,), jnp.int32)}, 1)
 
-    body = _make_worker_body(w, p, runtime.ops, explore_width=explore_width,
-                             batch=batch, n_vars=inst.n)
-    carry = {"incumbent": jnp.full((n_workers,), NEG, jnp.int32),
-             "explored": jnp.zeros((n_workers,), jnp.int32)}
+            body = _make_worker_body(w, p, runtime.ops,
+                                     explore_width=explore_width,
+                                     batch=batch, n_vars=inst.n)
+            carry = {"incumbent": jnp.full((n_workers,), NEG, jnp.int32),
+                     "explored": jnp.zeros((n_workers,), jnp.int32)}
 
-    carry = runtime.run(body, carry, max_rounds=max_supersteps,
-                        fused=fused_rounds)
+        carry = runtime.run(body, carry, max_rounds=max_supersteps,
+                            fused=fused_rounds)
 
-    stats = {
-        "supersteps": runtime.rounds_run,
-        "explored": int(jnp.sum(carry["explored"])),
-        "transferred": runtime.telemetry.total_transferred,
-        "per_worker_explored": [int(x) for x in carry["explored"]],
-        "telemetry": runtime.telemetry.summary(),
-        "backend": runtime.ops.resolved,
-        "execution": execution,
-    }
-    return int(carry["incumbent"][0]), stats
+        with spans.span("solve:result"):
+            optimum = int(carry["incumbent"][0])
+            stats = {
+                "supersteps": runtime.rounds_run,
+                "explored": int(jnp.sum(carry["explored"])),
+                "transferred": runtime.telemetry.total_transferred,
+                "per_worker_explored": [int(x) for x in carry["explored"]],
+                "telemetry": runtime.telemetry.summary(),
+                "backend": runtime.ops.resolved,
+                "execution": execution,
+            }
+    return optimum, stats

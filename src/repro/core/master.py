@@ -121,10 +121,12 @@ def gather_sizes(q: QueueState, *, worker_axis: str,
     controller or a drain check and every device takes the same branch.
     Works identically under ``vmap(axis_name=...)`` and ``shard_map``.
     """
-    sizes = lax.all_gather(q.size, worker_axis)  # (pod_size,) or (W,)
-    if pod_axis is None:
-        return sizes
-    return lax.all_gather(sizes, pod_axis).reshape(-1)  # (n_pods*pod_size,)
+    with jax.named_scope("master.sizes"):
+        sizes = lax.all_gather(q.size, worker_axis)  # (pod_size,) or (W,)
+        if pod_axis is None:
+            return sizes
+        # (n_pods * pod_size,)
+        return lax.all_gather(sizes, pod_axis).reshape(-1)
 
 
 def _resolve_ops(policy: StealPolicy, q: QueueState) -> bulk_ops.BulkOps:
@@ -164,32 +166,39 @@ def _dense_exchange(q, ops, policy, axis_name, n_workers, me, idx, src, amt
     stolen_amt = jnp.sum(jnp.where(steals_me, amt, 0))
     thief_id = jnp.argmax(steals_me).astype(jnp.int32)  # 0 when none (amt==0)
 
-    # Victim severs its tail block — single cursor bump linearizes.
-    # With a kernel-routed backend the detach is the Pallas ring-gather.
-    q, block, n_out = ops.steal_exact(q, stolen_amt,
-                                      max_steal=policy.max_steal)
+    with jax.named_scope("master.exchange"):
+        # Victim severs its tail block — single cursor bump linearizes.
+        # With a kernel-routed backend the detach is the Pallas
+        # ring-gather.
+        q, block, n_out = ops.steal_exact(q, stolen_amt,
+                                          max_steal=policy.max_steal)
 
-    # Outbox: one row per peer, only the thief's row is populated.
-    def _outbox(x):
-        out = jnp.zeros((n_workers,) + x.shape, x.dtype)
-        return out.at[thief_id].set(jnp.where(n_out > 0, x, jnp.zeros_like(x)))
+        # Outbox: one row per peer, only the thief's row is populated.
+        def _outbox(x):
+            out = jnp.zeros((n_workers,) + x.shape, x.dtype)
+            return out.at[thief_id].set(
+                jnp.where(n_out > 0, x, jnp.zeros_like(x)))
 
-    outbox = jax.tree_util.tree_map(_outbox, block)
-    counts = jnp.zeros((n_workers,), jnp.int32).at[thief_id].set(n_out)
+        outbox = jax.tree_util.tree_map(_outbox, block)
+        counts = jnp.zeros((n_workers,), jnp.int32).at[thief_id].set(n_out)
 
-    # One bulk exchange: row j of the inbox is what peer j sent to me.
-    inbox = jax.tree_util.tree_map(
-        lambda x: lax.all_to_all(x, axis_name, split_axis=0, concat_axis=0),
-        outbox,
-    )
-    counts_in = lax.all_to_all(counts, axis_name, split_axis=0, concat_axis=0)
+        # One bulk exchange: row j of the inbox is what peer j sent to me.
+        inbox = jax.tree_util.tree_map(
+            lambda x: lax.all_to_all(x, axis_name, split_axis=0,
+                                     concat_axis=0),
+            outbox,
+        )
+        counts_in = lax.all_to_all(counts, axis_name, split_axis=0,
+                                   concat_axis=0)
 
-    # Thief splices: at most one row is non-empty, blocks are pre-masked
-    # so a sum collapses the inbox without a gather.  With a kernel-routed
-    # backend the splice is the Pallas ring-scatter kernel.
-    recv_n = jnp.sum(counts_in)
-    recv = jax.tree_util.tree_map(lambda x: jnp.sum(x, axis=0), inbox)
-    q, _ = ops.push(q, recv, recv_n)
+    with jax.named_scope("master.splice"):
+        # Thief splices: at most one row is non-empty, blocks are
+        # pre-masked so a sum collapses the inbox without a gather.  With
+        # a kernel-routed backend the splice is the Pallas ring-scatter
+        # kernel.
+        recv_n = jnp.sum(counts_in)
+        recv = jax.tree_util.tree_map(lambda x: jnp.sum(x, axis=0), inbox)
+        q, _ = ops.push(q, recv, recv_n)
 
     bytes_moved = _payload_i32(n_workers * policy.max_steal
                                * _item_nbytes(q))
@@ -206,34 +215,37 @@ def _compact_exchange(q, ops, policy, axis_name, me, idx, sizes, src, amt
     any_transfer = jnp.any(amt > 0)
 
     def move(q):
-        # Victim side: how much the plan severs from me.  The detach is
-        # the cursor bump alone — the collective carries my raw window,
-        # so no masked intermediate block is ever materialized.
-        steals_me = (src == me) & (amt > 0) & (idx != me)
-        stolen_amt = jnp.sum(jnp.where(steals_me, amt, 0))
-        n_out = jnp.clip(stolen_amt, 0,
-                         jnp.minimum(q.size, jnp.int32(max_steal)))
-        window = ops.window(q, max_steal=max_steal)
-        gathered = jax.tree_util.tree_map(
-            lambda x: lax.all_gather(x, axis_name), window)
-        q = QueueState(buf=q.buf, lo=(q.lo + n_out) % cap,
-                       size=q.size - n_out)
+        with jax.named_scope("master.exchange"):
+            # Victim side: how much the plan severs from me.  The detach
+            # is the cursor bump alone — the collective carries my raw
+            # window, so no masked intermediate block is ever
+            # materialized.
+            steals_me = (src == me) & (amt > 0) & (idx != me)
+            stolen_amt = jnp.sum(jnp.where(steals_me, amt, 0))
+            n_out = jnp.clip(stolen_amt, 0,
+                             jnp.minimum(q.size, jnp.int32(max_steal)))
+            window = ops.window(q, max_steal=max_steal)
+            gathered = jax.tree_util.tree_map(
+                lambda x: lax.all_gather(x, axis_name), window)
+            q = QueueState(buf=q.buf, lo=(q.lo + n_out) % cap,
+                           size=q.size - n_out)
 
-        # Thief side: my row of the replicated plan names my victim; the
-        # count is re-derived from the same replicated inputs the victim
-        # clamped against (sizes gathered BEFORE any cursor moved), so
-        # victim and thief agree exactly.
-        my_src = src[me]
-        my_amt = amt[me]
-        is_thief = (my_amt > 0) & (my_src != me)
-        recv_n = jnp.where(
-            is_thief,
-            jnp.clip(my_amt, 0,
-                     jnp.minimum(sizes[my_src], jnp.int32(max_steal))),
-            0,
-        )
-        q, _ = ops.transfer(q, gathered, my_src, recv_n,
-                            max_steal=max_steal)
+        with jax.named_scope("master.splice"):
+            # Thief side: my row of the replicated plan names my victim;
+            # the count is re-derived from the same replicated inputs the
+            # victim clamped against (sizes gathered BEFORE any cursor
+            # moved), so victim and thief agree exactly.
+            my_src = src[me]
+            my_amt = amt[me]
+            is_thief = (my_amt > 0) & (my_src != me)
+            recv_n = jnp.where(
+                is_thief,
+                jnp.clip(my_amt, 0,
+                         jnp.minimum(sizes[my_src], jnp.int32(max_steal))),
+                0,
+            )
+            q, _ = ops.transfer(q, gathered, my_src, recv_n,
+                                max_steal=max_steal)
         return q
 
     # Replicated fast path: the plan is identical on every lane, so all
@@ -286,11 +298,13 @@ def superstep(
     idx = jnp.arange(n_workers, dtype=jnp.int32)
 
     # (1) master bookkeeping: gather sizes.
-    sizes = lax.all_gather(q.size, axis_name)  # (W,) identical on all lanes
+    with jax.named_scope("master.sizes"):
+        sizes = lax.all_gather(q.size, axis_name)  # (W,) same on all lanes
 
     # (2) replicated plan.
     if plan is None:
-        plan = plan_transfers(sizes, policy)  # (W, 2): row t = (victim, n)
+        with jax.named_scope("master.plan"):
+            plan = plan_transfers(sizes, policy)  # (W, 2): (victim, n)
     src, amt = plan[:, 0], plan[:, 1]
 
     # (3) the block exchange.
@@ -304,7 +318,8 @@ def superstep(
         raise ValueError(
             f"unknown exchange {exchange!r}; expected 'compact' or 'dense'")
 
-    sizes_after = lax.all_gather(q.size, axis_name)
+    with jax.named_scope("master.sizes"):
+        sizes_after = lax.all_gather(q.size, axis_name)
     if bulk_ops._env_check():
         # Sanitizer on (REPRO_CHECK=1, decided at trace time): assert in
         # trace that this level's exchange conserved its gathered sizes.

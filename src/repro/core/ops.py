@@ -586,7 +586,8 @@ class BulkOps:
         """Bulk push ``n`` items; returns ``(state, n_pushed)``."""
         if donate:
             return _donating(*self._flags()).push(q, batch, n)
-        return _push(q, batch, n, kernel=self.kernel_push)
+        with jax.named_scope("queue.push"):
+            return _push(q, batch, n, kernel=self.kernel_push)
 
     def pop(self, q: QueueState, *, donate: bool = False
             ) -> Tuple[QueueState, Pytree, jnp.ndarray]:
@@ -602,7 +603,8 @@ class BulkOps:
         ``(state, batch, n_popped)`` with ``batch`` rows >= n zeroed."""
         if donate:
             return _donating(*self._flags()).pop_bulk(q, max_n, n)
-        return _pop_bulk(q, max_n, n, kernel=self.kernel_pop)
+        with jax.named_scope("queue.pop"):
+            return _pop_bulk(q, max_n, n, kernel=self.kernel_pop)
 
     def steal(self, q: QueueState, proportion, *, max_steal: int,
               queue_limit: int = DEFAULT_QUEUE_LIMIT,
@@ -635,7 +637,8 @@ class BulkOps:
         caller's cursor bump)."""
         if donate:
             return _donating(*self._flags()).window(q, max_steal=max_steal)
-        return _window(q, max_steal=max_steal, kernel=self.kernel_steal)
+        with jax.named_scope("queue.window"):
+            return _window(q, max_steal=max_steal, kernel=self.kernel_steal)
 
     def transfer(self, q: QueueState, gathered: Pytree, src_row, n, *,
                  max_steal: int, donate: bool = False
@@ -646,8 +649,9 @@ class BulkOps:
         if donate:
             return _donating(*self._flags()).transfer(
                 q, gathered, src_row, n, max_steal=max_steal)
-        return _transfer(q, gathered, src_row, n, max_steal=max_steal,
-                         kernel=self.kernel_transfer)
+        with jax.named_scope("queue.transfer"):
+            return _transfer(q, gathered, src_row, n, max_steal=max_steal,
+                             kernel=self.kernel_transfer)
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,10 @@
 """repro.obs — observability for the steal runtime.
 
-Four cooperating pieces (DESIGN.md §11):
+Five cooperating pieces (DESIGN.md §11):
 
+* :mod:`repro.obs.spans` — host spans and counters of the runtime's
+  dispatch loop and per-call set-up, recorded only while a
+  ``jax.profiler`` session records, on the profiler's clock.
 * :mod:`repro.obs.phase` — per-round wall-clock attributed to
   ``worker_body`` / ``exchange`` / ``splice`` / ``adaptive_update`` via
   truncated-prefix re-execution (off by default; compile-identical and

@@ -33,8 +33,8 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
            "collect_telemetry", "collect_detector", "collect_runtime",
-           "collect_paged_queue", "collect_master", "runtime_metrics",
-           "master_metrics", "write_textfile"]
+           "collect_paged_queue", "collect_master", "collect_spans",
+           "runtime_metrics", "master_metrics", "write_textfile"]
 
 LabelKey = Tuple[Tuple[str, str], ...]
 
@@ -372,14 +372,41 @@ def collect_master(reg: MetricsRegistry, master,
     return reg
 
 
+def collect_spans(reg: MetricsRegistry,
+                  prefix: str = "repro") -> MetricsRegistry:
+    """The host spans and counters :mod:`repro.obs.spans` recorded while a
+    profiler session ran: calls, inclusive and self seconds by span, and
+    the counters by name.  Adds nothing when nothing was recorded."""
+    from repro.obs import spans
+
+    snap = spans.snapshot()
+    if snap["spans"]:
+        calls = reg.counter(f"{prefix}_span_calls_total",
+                            "host spans closed, by span")
+        total = reg.counter(f"{prefix}_span_seconds_total",
+                            "host seconds inside each span")
+        own = reg.counter(f"{prefix}_span_self_seconds_total",
+                          "host seconds inside each span, less its children")
+        for name, agg in snap["spans"].items():
+            calls.set_total(agg["n"], span=name)
+            total.set_total(agg["total_s"], span=name)
+            own.set_total(agg["self_s"], span=name)
+    if snap["counters"]:
+        events = reg.counter(f"{prefix}_span_events_total",
+                             "program counters recorded with the spans")
+        for name, n in snap["counters"].items():
+            events.set_total(n, counter=name)
+    return reg
+
+
 # -- convenience entry points ------------------------------------------------
 
 
 def runtime_metrics(rt, registry: Optional[MetricsRegistry] = None
                     ) -> MetricsRegistry:
     """One-call poll of a runtime: a fresh (or given) registry with
-    :func:`collect_runtime` applied."""
-    return collect_runtime(registry or MetricsRegistry(), rt)
+    :func:`collect_runtime` and :func:`collect_spans` applied."""
+    return collect_spans(collect_runtime(registry or MetricsRegistry(), rt))
 
 
 def master_metrics(master, registry: Optional[MetricsRegistry] = None

@@ -42,17 +42,14 @@ results are bit-identical with the probe on or off.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
-import os
 import time
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import numpy as np
 
-__all__ = ["PHASES", "PhaseSample", "PhaseProbe", "timed_call",
-           "trace_span"]
+__all__ = ["PHASES", "PhaseSample", "PhaseProbe", "timed_call"]
 
 # Phase order is load-bearing: calibration deltas and trace children
 # are emitted in this order.
@@ -97,28 +94,6 @@ def timed_call(fn, args) -> Tuple[float, Any]:
     out = fn(*args)
     out = jax.block_until_ready(out)
     return time.perf_counter() - t0, out
-
-
-@contextlib.contextmanager
-def trace_span(name: str):
-    """Opt-in ``jax.profiler`` wrapping of one fused dispatch: when
-    ``REPRO_TRACE=<dir>`` is set, the dispatch runs inside a profiler
-    trace written under that directory (XLA/TensorBoard-level detail —
-    complements, not replaces, the logical Chrome trace
-    :mod:`repro.obs.trace` builds from telemetry).  A no-op otherwise,
-    and degrades to a no-op if a trace is already active."""
-    trace_dir = os.environ.get("REPRO_TRACE")
-    if not trace_dir:
-        yield
-        return
-    try:
-        with jax.profiler.trace(os.path.join(trace_dir, name)):
-            yield
-    except RuntimeError:
-        # A profiler session is already running (nested fused dispatch,
-        # or the user armed their own) — observability must never turn
-        # into a crash.
-        yield
 
 
 class PhaseProbe:

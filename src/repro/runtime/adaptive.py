@@ -32,6 +32,7 @@ from __future__ import annotations
 import dataclasses
 from typing import List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -76,17 +77,19 @@ def adaptive_update(proportion, sizes, *, policy: StealPolicy,
     (victim, thief) this round there is no transfer to size, so hold
     rather than servo on zero signal.
     """
-    sizes = jnp.asarray(sizes)
-    p = jnp.asarray(proportion, jnp.float32)
-    n_idle = jnp.sum((sizes <= policy.low_watermark).astype(jnp.int32))
-    n_busy = jnp.sum((sizes >= policy.high_watermark).astype(jnp.int32))
-    ratio = (n_idle.astype(jnp.float32)
-             / jnp.maximum(n_idle + n_busy, 1).astype(jnp.float32))
-    target = jnp.clip(jnp.float32(policy.proportion) * 2.0 * ratio,
-                      0.125, 0.75)
-    p_new = p + jnp.float32(config.gain) * (target - p)
-    p_new = jnp.clip(p_new, config.min_proportion, config.max_proportion)
-    return jnp.where((n_idle > 0) & (n_busy > 0), p_new, p)
+    with jax.named_scope("adaptive"):
+        sizes = jnp.asarray(sizes)
+        p = jnp.asarray(proportion, jnp.float32)
+        n_idle = jnp.sum((sizes <= policy.low_watermark).astype(jnp.int32))
+        n_busy = jnp.sum((sizes >= policy.high_watermark).astype(jnp.int32))
+        ratio = (n_idle.astype(jnp.float32)
+                 / jnp.maximum(n_idle + n_busy, 1).astype(jnp.float32))
+        target = jnp.clip(jnp.float32(policy.proportion) * 2.0 * ratio,
+                          0.125, 0.75)
+        p_new = p + jnp.float32(config.gain) * (target - p)
+        p_new = jnp.clip(p_new, config.min_proportion,
+                         config.max_proportion)
+        return jnp.where((n_idle > 0) & (n_busy > 0), p_new, p)
 
 
 class AdaptiveController:

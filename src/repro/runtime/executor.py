@@ -54,6 +54,7 @@ from repro.core import master as master_ops
 from repro.core import ops as bulk_ops
 from repro.core.policy import StealPolicy
 from repro.core.sharded_queue import make_sharded_queues
+from repro.obs import spans
 from repro.runtime import resilience
 from repro.runtime.adaptive import (AdaptiveConfig, AdaptiveController,
                                     adaptive_update)
@@ -89,9 +90,13 @@ class LiftedJit:
                                   *jax.tree_util.tree_leaves(args))
         return jax.tree_util.tree_unflatten(out_tree, out)
 
-    def _key(self, args):
+    @staticmethod
+    def _signature(args):
         leaves, tree = jax.tree_util.tree_flatten(args)
-        key = (tree, tuple(jax.typeof(x) for x in leaves))
+        return (tree, tuple(jax.typeof(x) for x in leaves))
+
+    def _key(self, args):
+        key = self._signature(args)
         if key not in self._traced:
             closed, shape = jax.make_jaxpr(self._fn, return_shape=True)(
                 *args)
@@ -101,8 +106,15 @@ class LiftedJit:
         return key
 
     def __call__(self, *args):
-        key = self._key(args)
-        return self._jit(key, self._traced[key][1], *args)
+        key = self._signature(args)
+        if key in self._traced:
+            return self._jit(key, self._traced[key][1], *args)
+        # A new signature: trace it, then the first call lowers and
+        # compiles it (or loads it from the persistent cache).
+        with spans.span("steal:compile"):
+            spans.count("steal.compiles")
+            self._key(args)
+            return self._jit(key, self._traced[key][1], *args)
 
     def lower(self, *args) -> jax.stages.Lowered:
         """The program a call with ``args`` runs, for inspection."""
@@ -168,7 +180,8 @@ def make_lane_step(policy: StealPolicy, ops: bulk_ops.BulkOps,
     def lane(q, carry, proportion, ctx):
         del ctx  # round index only; same signature as the fault lane
         if worker_fn is not None:
-            q, carry = worker_fn(q, carry)
+            with jax.named_scope("worker_body"):
+                q, carry = worker_fn(q, carry)
         if stage == "worker":
             return q, carry, master_ops.probe_token(q)
         pol = dataclasses.replace(policy, proportion=proportion)
@@ -868,53 +881,63 @@ class StealRuntime:
         once, outside the loop) — a fresh lambda/partial per call would
         recompile the superstep every round.
         """
-        fn = self._compiled.get(worker_fn)
-        if fn is None:
-            fn = self._compiled[worker_fn] = self._compile(worker_fn)
-        if carry is None:
-            carry = jnp.zeros((self.n_workers,), jnp.int32)
-        snap = self._pre_dispatch_snapshot(worker_fn)
-        proportion = self.proportion
-        probed = self._probe_enabled()
-        args = (self.queues, carry, jnp.float32(proportion),
-                self._ctx(self.rounds_run))
-        t_worker = t_exchange = 0.0
-        if probed:
-            # Direct attribution: time the worker and exchange PREFIX
-            # programs on the immutable inputs the committed round is
-            # about to consume (pure, results discarded), then fence the
-            # unchanged full round.
-            jax.block_until_ready(args)
-            t_worker = self._probe_time(worker_fn, "worker", args)
-            t_exchange = self._probe_time(worker_fn, "exchange", args)
-        t0 = time.perf_counter()
-        self.queues, carry, stats = fn(*args)
-        if probed:
-            jax.block_until_ready((self.queues, carry, stats))
-        sizes = self.sizes()
-        wall_s = time.perf_counter() - t0
-        n_steals, n_transferred, bytes_moved = self._round_counts(stats)
-        if self._check:
-            self._post_dispatch_checks(
-                [jax.tree_util.tree_map(np.asarray, stats)], snap,
-                context="StealRuntime.round")
-        t_a0 = time.perf_counter()
-        if self.controller is not None:
-            self.controller.update(self._controller_sizes(sizes))
-        phases = None
-        if probed:
-            phases = self._phase_probe.direct_sample(
-                t_worker=t_worker, t_exchange=t_exchange, t_full=wall_s,
-                t_adaptive=time.perf_counter() - t_a0).as_record()
-        self.telemetry.record(sizes=sizes, n_steals=n_steals,
-                              n_transferred=n_transferred,
-                              proportion=proportion,
-                              bytes_moved=bytes_moved,
-                              phases=phases)
-        r0 = self.rounds_run
-        self.rounds_run += 1
-        self._feed_detector(r0, 1, wall_s=wall_s)
-        self._maybe_snapshot()
+        with spans.span("steal:round"):
+            with spans.span("steal:enqueue"):
+                fn = self._compiled.get(worker_fn)
+                if fn is None:
+                    fn = self._compiled[worker_fn] = self._compile(worker_fn)
+                if carry is None:
+                    carry = jnp.zeros((self.n_workers,), jnp.int32)
+                snap = self._pre_dispatch_snapshot(worker_fn)
+                proportion = self.proportion
+                probed = self._probe_enabled()
+                args = (self.queues, carry, jnp.float32(proportion),
+                        self._ctx(self.rounds_run))
+                t_worker = t_exchange = 0.0
+                if probed:
+                    # Direct attribution: time the worker and exchange
+                    # PREFIX programs on the immutable inputs the
+                    # committed round is about to consume (pure, results
+                    # discarded), then fence the unchanged full round.
+                    jax.block_until_ready(args)
+                    t_worker = self._probe_time(worker_fn, "worker", args)
+                    t_exchange = self._probe_time(worker_fn, "exchange",
+                                                  args)
+                t0 = time.perf_counter()
+                self.queues, carry, stats = fn(*args)
+            with spans.span("steal:wait"):
+                if probed:
+                    jax.block_until_ready((self.queues, carry, stats))
+                sizes = self.sizes()
+                wall_s = time.perf_counter() - t0
+            with spans.span("steal:readback"):
+                n_steals, n_transferred, bytes_moved = self._round_counts(
+                    stats)
+            with spans.span("steal:record"):
+                spans.count("steal.dispatches")
+                spans.count("steal.rounds")
+                if self._check:
+                    self._post_dispatch_checks(
+                        [jax.tree_util.tree_map(np.asarray, stats)], snap,
+                        context="StealRuntime.round")
+                t_a0 = time.perf_counter()
+                if self.controller is not None:
+                    self.controller.update(self._controller_sizes(sizes))
+                phases = None
+                if probed:
+                    phases = self._phase_probe.direct_sample(
+                        t_worker=t_worker, t_exchange=t_exchange,
+                        t_full=wall_s,
+                        t_adaptive=time.perf_counter() - t_a0).as_record()
+                self.telemetry.record(sizes=sizes, n_steals=n_steals,
+                                      n_transferred=n_transferred,
+                                      proportion=proportion,
+                                      bytes_moved=bytes_moved,
+                                      phases=phases)
+                r0 = self.rounds_run
+                self.rounds_run += 1
+                self._feed_detector(r0, 1, wall_s=wall_s)
+                self._maybe_snapshot()
         return carry, stats
 
     def run_fused(self, k: int, worker_fn: Optional[WorkerFn] = None,
@@ -947,35 +970,54 @@ class StealRuntime:
         """
         if k < 1:
             raise ValueError(f"k must be >= 1, got {k}")
-        key = ("fused", worker_fn, k, until_drained)
-        fn = self._compiled.get(key)
-        if fn is None:
-            fn = self._compiled[key] = self._compile_fused(
-                worker_fn, k, until_drained)
-        if carry is None:
-            carry = jnp.zeros((self.n_workers,), jnp.int32)
-        snap = self._pre_dispatch_snapshot(worker_fn)
-        p0 = jnp.float32(self.proportion)
-        probed = self._probe_enabled()
-        args = (self.queues, carry, p0, self._ctx(self.rounds_run))
-        if probed:
-            # Calibrated attribution: refresh the phase fractions on the
-            # current state when stale (four pure prefix dispatches per
-            # calibrate_every rounds), fence, then time the one real
-            # dispatch end to end.
-            jax.block_until_ready(args)
-            if self._phase_probe.needs_calibration(worker_fn,
-                                                   self.rounds_run):
-                self._probe_calibrate(worker_fn, args)
-        from repro.obs.phase import trace_span
+        with spans.span("steal:run_fused"):
+            with spans.span("steal:enqueue"):
+                key = ("fused", worker_fn, k, until_drained)
+                fn = self._compiled.get(key)
+                if fn is None:
+                    fn = self._compiled[key] = self._compile_fused(
+                        worker_fn, k, until_drained)
+                if carry is None:
+                    carry = jnp.zeros((self.n_workers,), jnp.int32)
+                snap = self._pre_dispatch_snapshot(worker_fn)
+                p0 = jnp.float32(self.proportion)
+                probed = self._probe_enabled()
+                args = (self.queues, carry, p0, self._ctx(self.rounds_run))
+                if probed:
+                    # Calibrated attribution: refresh the phase fractions
+                    # on the current state when stale (four pure prefix
+                    # dispatches per calibrate_every rounds), fence, then
+                    # time the one real dispatch end to end.
+                    jax.block_until_ready(args)
+                    if self._phase_probe.needs_calibration(worker_fn,
+                                                           self.rounds_run):
+                        self._probe_calibrate(worker_fn, args)
+                t0 = time.perf_counter()
+                self.queues, carry, p_final, tele, rounds = fn(*args)
+            with spans.span("steal:wait"):
+                rounds = int(rounds)
+            with spans.span("steal:readback"):
+                # ONE host read-back for the whole fused run.
+                tele = jax.tree_util.tree_map(np.asarray, tele)
+            with spans.span("steal:record"):
+                wall_s = time.perf_counter() - t0
+                spans.count("steal.dispatches")
+                spans.count("steal.rounds", rounds)
+                stats = self._record_fused(tele, rounds, p_final, wall_s,
+                                           worker_fn, snap, probed)
+                if until_drained:
+                    stats = jax.tree_util.tree_map(lambda x: x[:rounds],
+                                                   stats)
+        if until_drained:
+            return carry, stats, rounds
+        return carry, stats
 
-        t0 = time.perf_counter()
-        with trace_span(f"run_fused_k{k}"):
-            self.queues, carry, p_final, tele, rounds = fn(*args)
-            rounds = int(rounds)
-            # ONE host read-back for the whole fused run.
-            tele = jax.tree_util.tree_map(np.asarray, tele)
-        wall_s = time.perf_counter() - t0
+    def _record_fused(self, tele, rounds: int, p_final, wall_s: float,
+                      worker_fn, snap, probed: bool):
+        """Host side of a fused block once its telemetry is read back:
+        per-round telemetry records, sanitizer checks, the controller,
+        the detector and the snapshot cadence.  Returns the stacked
+        per-round stats."""
         stats = tele["stats"]
         per_round_s = wall_s / rounds if rounds > 0 else 0.0
         phases = None
@@ -1006,10 +1048,7 @@ class StealRuntime:
         self.rounds_run += rounds
         self._feed_detector(r0, rounds, wall_s=wall_s)
         self._maybe_snapshot()
-        if until_drained:
-            stats = jax.tree_util.tree_map(lambda x: x[:rounds], stats)
-            return carry, stats, rounds
-        return carry, stats
+        return stats
 
     def lower(self, worker_fn: Optional[WorkerFn] = None,
               carry: Optional[Pytree] = None, *,

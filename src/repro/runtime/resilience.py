@@ -384,7 +384,8 @@ def make_resilient_lane(policy: StealPolicy, ops, worker_fn, *,
         i_am_delayed = (r >= ctx["delay_from"][me]) & (r < ctx["delay_until"][me])
 
         if worker_fn is not None:
-            q_new, carry_new = worker_fn(q, carry)
+            with jax.named_scope("worker_body"):
+                q_new, carry_new = worker_fn(q, carry)
             skip = i_am_dead | i_am_delayed
             q = _select(skip, q, q_new)
             carry = _select(skip, carry, carry_new)
@@ -447,7 +448,8 @@ def make_resilient_lane(policy: StealPolicy, ops, worker_fn, *,
         i_am_delayed = (r >= ctx["delay_from"][me]) & (r < ctx["delay_until"][me])
 
         if worker_fn is not None:
-            q_new, carry_new = worker_fn(q, carry)
+            with jax.named_scope("worker_body"):
+                q_new, carry_new = worker_fn(q, carry)
             skip = i_am_dead | i_am_delayed
             q = _select(skip, q, q_new)
             carry = _select(skip, carry, carry_new)
