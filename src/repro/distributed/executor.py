@@ -81,7 +81,8 @@ class MeshStealRuntime(StealRuntime):
         names match the vmapped runtime's, so worker bodies written for
         one mode run unmodified in the other.
       capacity / item_spec / policy / adaptive / adaptive_config /
-      backend / max_pop: exactly as :class:`~repro.runtime.StealRuntime`.
+      backend / max_pop / programs: exactly as
+      :class:`~repro.runtime.StealRuntime`.
     """
 
     def __init__(self, mesh: Mesh, capacity: int, item_spec, **kwargs):

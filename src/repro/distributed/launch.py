@@ -41,7 +41,8 @@ def launch_runtime(n_workers: int, capacity: int, item_spec, *,
     optionally pins the mesh instead of building one over the first
     ``n_workers`` process devices; it must agree with ``n_workers`` /
     ``pod_size``.  Remaining keywords (``policy`` / ``adaptive`` /
-    ``adaptive_config`` / ``backend`` / ``max_pop`` / ``fault_plan``)
+    ``adaptive_config`` / ``backend`` / ``max_pop`` / ``fault_plan`` /
+    ``programs``)
     pass through to the runtime unchanged.
     """
     if execution == "vmap":

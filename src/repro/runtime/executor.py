@@ -239,6 +239,15 @@ class StealRuntime:
         fault-free executor.  Composes with ``pod_size``: on the
         hierarchical grid a dead LANE drains intra-pod, an entirely
         dead POD escalates to a cross-pod recovery plan.
+      programs: the table the compiled rounds and fused blocks are kept
+        in, keyed by ``worker_fn`` (and ``k`` for fused blocks).  A
+        caller that builds runtimes of one geometry and one policy again
+        and again passes the same table to each, with the same
+        ``worker_fn`` object, and the programs are traced and compiled
+        once; queues, controller, telemetry and ``rounds_run`` stay per
+        runtime.  Only runtimes that would trace the same program may
+        share a table.  ``None`` (default): a table of this runtime's
+        own.
     """
 
     def __init__(self, n_workers: int, capacity: int, item_spec: Pytree, *,
@@ -251,7 +260,8 @@ class StealRuntime:
                  pod_size: Optional[int] = None,
                  pod_axis: str = "pods",
                  queue_sharding=None,
-                 fault_plan: Optional[FaultPlan] = None):
+                 fault_plan: Optional[FaultPlan] = None,
+                 programs: Optional[Dict[Any, Callable]] = None):
         if pod_size is not None and n_workers % pod_size != 0:
             raise ValueError(
                 f"n_workers={n_workers} not divisible by pod_size={pod_size}")
@@ -286,7 +296,8 @@ class StealRuntime:
         self.telemetry = Telemetry(item_bytes=item_nbytes(item_spec),
                                    capacity=capacity)
         self.rounds_run = 0
-        self._compiled: Dict[Any, Callable] = {}
+        self._compiled: Dict[Any, Callable] = (
+            {} if programs is None else programs)
         # Phase probe (repro.obs.phase): truncated-prefix programs live
         # in their OWN cache so elastic.compile_count — which audits
         # ``_compiled`` as the zero-recompile gate — never sees them.

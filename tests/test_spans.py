@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.dd import parallel
 from repro.core.dd.knapsack import dp_solve, random_instance
 from repro.core.dd.parallel import parallel_solve
 from repro.core.policy import StealPolicy
@@ -144,18 +145,21 @@ def test_compile_fires_once_per_new_signature(tmp_path):
     assert fn._cache_size() == 2
 
 
-def test_two_solves_count_two_calls_and_two_compiles(tmp_path):
+def test_two_solves_count_two_calls_one_compile_one_reuse(tmp_path):
     inst = random_instance(8, seed=3)
+    parallel._PROGRAMS.clear()
     with recording(tmp_path):
         for _ in range(2):
             opt, stats = parallel_solve(inst, n_workers=4)
             assert opt == dp_solve(inst)
     snap = spans.snapshot()
     assert snap["counters"]["solve.calls"] == 2
-    assert snap["counters"]["steal.compiles"] == 2
-    for name in ("solve:call", "solve:build", "solve:result",
-                 "steal:compile"):
+    # The second call runs the first one's program.
+    assert snap["counters"]["steal.compiles"] == 1
+    assert snap["counters"]["solve.programs_reused"] == 1
+    for name in ("solve:call", "solve:build", "solve:result"):
         assert snap["spans"][name]["n"] == 2
+    assert snap["spans"]["steal:compile"]["n"] == 1
     # steal:compile runs inside the first dispatch's enqueue.
     assert (snap["spans"]["steal:enqueue"]["self_s"]
             < snap["spans"]["steal:enqueue"]["total_s"])
