@@ -15,6 +15,8 @@ Layers:
   host_queue    faithful host-threaded port of the paper's Listings 1-4,
                 behind the HostQueue protocol
   dd            decision-diagram branch-and-bound solver (paper's application)
+  uts           UTS binomial trees (SHA-1 spawning on the device) searched
+                on the steal runtime, with a hashlib reference walk
 
 (The pre-BulkOps ``use_kernel`` dialect — module-level queue ops and
 their ``*_inplace`` variants — had its one deprecation release at PR 3

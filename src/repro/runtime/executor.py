@@ -927,6 +927,8 @@ class StealRuntime:
             with spans.span("steal:record"):
                 spans.count("steal.dispatches")
                 spans.count("steal.rounds")
+                spans.count("steal.thefts", n_steals)
+                spans.count("steal.items_moved", n_transferred)
                 if self._check:
                     self._post_dispatch_checks(
                         [jax.tree_util.tree_map(np.asarray, stats)], snap,
@@ -1038,6 +1040,7 @@ class StealRuntime:
             # copies the values out.
             phases = self._phase_probe.estimated_sample(
                 worker_fn, per_round_s, n=rounds).as_record()
+        thefts = moved = 0
         for r in range(rounds):
             stats_r = jax.tree_util.tree_map(lambda x: x[r], stats)
             n_steals, n_transferred, bytes_moved = self._round_counts(stats_r)
@@ -1047,6 +1050,10 @@ class StealRuntime:
                                   proportion=float(tele["proportion"][r]),
                                   bytes_moved=bytes_moved,
                                   phases=phases)
+            thefts += n_steals
+            moved += n_transferred
+        spans.count("steal.thefts", thefts)
+        spans.count("steal.items_moved", moved)
         if self._check:
             self._post_dispatch_checks(
                 [jax.tree_util.tree_map(lambda x, _r=r: x[_r], stats)

@@ -139,6 +139,60 @@ def test_queue_transfer_equals_select_then_push():
                                       np.asarray(two_step))
 
 
+# ----------------------------------------- UTS nodes at the cell's geometry
+
+# (op, head or lo, n): six int32 words a node (digest bit patterns and
+# height) in a 16,384-slot ring; pushes of up to 512 (64 parents x 8
+# children) and the root's 2,000 children, pops of 64, windows of 8,192.
+UTS_CASES = [
+    ("push", 16_200, 488),     # wraps past the ring's end
+    ("push", 3, 512),
+    ("seed", 16_000, 2000),    # padded to 2,048 rows; wraps
+    ("pop", 16_350, 64),       # the newest 64 straddle the end
+    ("window", 16_300, 8192),
+    ("transfer", 16_383, 8192),
+]
+
+
+@pytest.mark.parametrize("case", UTS_CASES, ids=lambda c: f"{c[0]}-{c[1]}")
+def test_uts_geometry_matches_ref(case):
+    """The kernels move 24-byte UTS nodes bit-exactly as the reference."""
+    from repro.kernels.queue_push.kernel import ring_scatter, ring_slice
+    from repro.kernels.queue_push.ref import ring_scatter_ref, ring_slice_ref
+    from repro.kernels.queue_steal.kernel import ring_gather
+
+    op, at, n = case
+    cap, width = 16_384, 6
+    ks = jax.random.split(KEY, 2)
+    words = lambda k, shape: jax.random.randint(  # noqa: E731
+        k, shape, jnp.iinfo(jnp.int32).min, jnp.iinfo(jnp.int32).max,
+        jnp.int32)
+    buf = words(ks[0], (cap, width))
+    if op in ("push", "seed"):
+        rows = 2048 if op == "seed" else 512
+        batch = words(ks[1], (rows, width))
+        out_k = ring_scatter(buf, batch, jnp.int32(at), jnp.int32(n),
+                             interpret=True)
+        out_r = ring_scatter_ref(buf, batch, at, n)
+    elif op == "pop":
+        size = 200
+        out_k = ring_slice(buf, jnp.int32(at), jnp.int32(size), jnp.int32(n),
+                           n, interpret=True)
+        out_r = ring_slice_ref(buf, at, size, n, n)
+    elif op == "window":
+        out_k = ring_gather(buf, jnp.int32(at), jnp.int32(n), n,
+                            interpret=True)
+        out_r = ring_gather_ref(buf, at, n, n)
+    else:
+        gathered = words(ks[1], (2, n, width))
+        out_k = transfer_splice(buf, gathered, jnp.int32(at), jnp.int32(1),
+                                jnp.int32(n // 2), max_steal=n,
+                                interpret=True)
+        out_r = ring_transfer_ref(buf, gathered.reshape(-1, width), at, n,
+                                  n // 2)
+    np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
+
+
 # --------------------------------------------------------------- ssd_scan
 
 SSD_CASES = [

@@ -133,6 +133,21 @@ def test_children_tile_the_dispatch(mode, parent, tmp_path):
     assert snap["counters"]["steal.rounds"] == rt.rounds_run
 
 
+@pytest.mark.parametrize("mode", ["drained", "scan", "round"])
+def test_theft_counters_equal_the_telemetry(mode, tmp_path):
+    """``steal.thefts`` and ``steal.items_moved`` are the per-round
+    ``n_steals`` and ``n_transferred`` the runtime records, summed."""
+    rt = _make_rt()
+    with recording(tmp_path):
+        _drive(rt, mode)
+    counters = spans.snapshot()["counters"]
+    thefts = sum(rec.n_steals for rec in rt.telemetry.rounds)
+    moved = sum(rec.n_transferred for rec in rt.telemetry.rounds)
+    assert thefts > 0 and moved > 0
+    assert counters["steal.thefts"] == thefts
+    assert counters["steal.items_moved"] == moved
+
+
 def test_compile_fires_once_per_new_signature(tmp_path):
     fn = LiftedJit(lambda x: x * 2 + 1)
     with recording(tmp_path):

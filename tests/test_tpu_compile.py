@@ -63,6 +63,12 @@ MAIN_PATH = [
     ("gather", 16384, 1, 1024, I32),       # max_steal
     ("transfer", 16384, 1, 1024, I32),
     ("scatter", 16384, 1, 1, I32),         # the one-item seeding push
+    # UTS T3 lanes: a node is six int32 words (digest, height).
+    ("scatter", 16384, 6, 512, I32),       # worker push of 64 x 8 children
+    ("slice", 16384, 6, 64, I32),          # worker pop of 64
+    ("gather", 16384, 6, 8192, I32),       # max_steal
+    ("transfer", 16384, 6, 8192, I32),
+    ("scatter", 16384, 6, 2048, I32),      # the root's 2,000 children
     # DD solver items: pop 4, push batch x explore_width = 32.
     ("slice", 4096, 1, 4, I32),
     ("scatter", 4096, 1, 32, I32),
