@@ -25,9 +25,10 @@ hierarchical supersteps).  Each device owns exactly one queue lane:
   without the host in the loop, and the drain check is a replicated
   cross-shard size reduction (every device takes the same exit branch).
 * **Exact cross-host telemetry** — each shard stacks its OWN lane's
-  per-round ``RebalanceStats`` counters; shard_map's output specs gather
-  them back into the vmapped runtime's exact ``(k, W, ...)`` lane
-  layout, so the one shared reduction
+  per-round ``RebalanceStats`` counters; one all-gather of them all at
+  the block edge puts them back into the vmapped runtime's exact ``(k, W, ...)``
+  lane layout, replicated and packed into the one buffer the host
+  reads, so the one shared reduction
   (:func:`repro.runtime.telemetry.reduce_round_stats`) assembles the
   same exact :class:`~repro.runtime.telemetry.RoundRecord`s — including
   ``bytes_moved`` / ``bytes_moved_xpod`` — from per-shard counters.
@@ -52,8 +53,8 @@ from repro.core import master as master_ops
 from repro.core import ops as bulk_ops
 from repro.runtime import resilience
 from repro.runtime.adaptive import adaptive_update
-from repro.runtime.executor import (LiftedJit, StealRuntime, WorkerFn,
-                                    make_lane_step)
+from repro.runtime.executor import (FusedProgram, ReadbackLayout,
+                                    StealRuntime, WorkerFn, make_lane_step)
 
 __all__ = ["MeshStealRuntime"]
 
@@ -219,11 +220,32 @@ class MeshStealRuntime(StealRuntime):
         """The whole k-round loop INSIDE one shard_map block: scan (or
         early-exit while_loop) over the shared round body, collectives
         and the adaptive carry never leaving the devices; telemetry
-        stacked per shard and gathered once at the block edge."""
+        stacked per shard, all-gathered once at the block edge into the
+        vmapped runtime's ``(k, W, ...)`` layout and packed with the
+        round count and the final proportion into one replicated buffer,
+        so the host reads one shard once."""
         one_round = self._fused_round(worker_fn)
         lane, entry = self._lane_spec, self._lane_entry
         axes = self._axes_tuple()
         ctx_spec = resilience.ctx_specs(self.fault is not None)
+        layout = ReadbackLayout()
+
+        W = self.n_workers
+
+        def gather_lanes(tree):
+            """One all-gather for every per-lane ``(k, 1, ...)`` int32
+            leaf, as one row a lane; returns them in the vmapped
+            runtime's ``(k, W, ...)`` layout."""
+            leaves, treedef = jax.tree_util.tree_flatten(tree)
+            rows = lax.all_gather(
+                jnp.concatenate([x.reshape(-1) for x in leaves]), entry)
+            out, at = [], 0
+            for x in leaves:
+                y = rows[:, at:at + x.size].reshape((W,) + x.shape)
+                out.append(jnp.moveaxis(y, 0, 1).reshape(
+                    x.shape[:1] + (W,) + x.shape[2:]))
+                at += x.size
+            return jax.tree_util.tree_unflatten(treedef, out)
 
         def local_fused(qs, carry, p0, ctx0):
             q, c = _strip_lane(qs), _strip_lane(carry)
@@ -257,16 +279,18 @@ class MeshStealRuntime(StealRuntime):
                     cond, body,
                     (q, c, p0, ctx0, jnp.int32(0), tele0, total0))
 
-            return _add_lane(q), _add_lane(c), p, tele, rounds
+            tele = dict(gather_lanes({"stats": tele["stats"],
+                                      "sizes": tele["sizes"]}),
+                        proportion=tele["proportion"])
+            return (_add_lane(q), _add_lane(c), p,
+                    layout.pack(rounds, p, tele))
 
-        tele_spec = {"stats": P(None, entry), "sizes": P(None, entry),
-                     "proportion": P(None)}
         fused = jax.shard_map(
             local_fused, mesh=self.mesh,
             in_specs=(lane, lane, P(), ctx_spec),
-            out_specs=(lane, lane, P(), tele_spec, P()),
+            out_specs=(lane, lane, P(), P()),
             check_vma=False)
-        return LiftedJit(fused, self._donate_argnums())
+        return FusedProgram(fused, layout, self._donate_argnums())
 
     # -- resilience: elastic state shardings ---------------------------------
 

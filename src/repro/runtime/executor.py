@@ -125,6 +125,59 @@ class LiftedJit:
         return self._jit._cache_size()
 
 
+class ReadbackLayout:
+    """Everything the host reads after a fused dispatch, in ONE int32
+    vector: the executed round count, the final proportion, then each
+    telemetry leaf flattened (4-byte dtypes other than int32, the float32
+    proportions among them, are bit-cast, so every value comes back
+    exactly).  Each read from the device is a hand-off to the runtime's
+    threads that costs hundreds of microseconds on the chip whatever its
+    size, so a dispatch makes one read, not one a leaf.
+
+    :meth:`pack` runs while the block is traced and records where each
+    leaf sits from the traced shapes; :meth:`unpack` rebuilds the
+    telemetry tree from the host copy with slices and views, reading
+    nothing more from the device."""
+
+    def __init__(self):
+        self._tree = None
+        self._leaves: Tuple[Tuple[int, int, tuple, np.dtype], ...] = ()
+
+    @staticmethod
+    def _bits(x) -> jax.Array:
+        if x.dtype != jnp.int32:
+            x = lax.bitcast_convert_type(x, jnp.int32)
+        return x.reshape(-1)
+
+    def pack(self, rounds, p_final, tele) -> jax.Array:
+        leaves, self._tree = jax.tree_util.tree_flatten(tele)
+        fields, at = [], 2
+        for x in leaves:
+            stop = at + x.size
+            fields.append((at, stop, tuple(x.shape), np.dtype(x.dtype)))
+            at = stop
+        self._leaves = tuple(fields)
+        return jnp.concatenate(
+            [self._bits(x) for x in (rounds, p_final, *leaves)])
+
+    def unpack(self, buf: np.ndarray) -> Tuple[int, float, Pytree]:
+        """``(rounds, p_final, tele)`` from the host copy of the buffer."""
+        leaves = [buf[a:b].view(dtype).reshape(shape)
+                  for a, b, shape, dtype in self._leaves]
+        return (int(buf[0]), float(buf[1:2].view(np.float32)[0]),
+                jax.tree_util.tree_unflatten(self._tree, leaves))
+
+
+class FusedProgram(LiftedJit):
+    """A fused block ``(qs, carry, p0, ctx0) -> (qs, carry, p, packed)``
+    and the :class:`ReadbackLayout` its ``packed`` output follows."""
+
+    def __init__(self, fn: Callable, layout: ReadbackLayout,
+                 donate_argnums: tuple = ()):
+        super().__init__(fn, donate_argnums)
+        self.layout = layout
+
+
 def make_lane_step(policy: StealPolicy, ops: bulk_ops.BulkOps,
                    worker_fn: Optional[WorkerFn], *, axis_name: str,
                    pod_axis: Optional[str] = None,
@@ -689,10 +742,13 @@ class StealRuntime:
         same round body that exits as soon as every lane is empty (checked
         on device, before each round), writing telemetry into
         preallocated ``(k, ...)`` slots and returning the executed round
-        count."""
+        count.  The round count, the final proportion and the telemetry
+        leave the device packed in one int32 buffer
+        (:class:`ReadbackLayout`)."""
         step = self._make_step(worker_fn)
         policy, controller = self.policy, self.controller
         config = controller.config if controller else None
+        layout = ReadbackLayout()
 
         def one_round(qs, carry, p, ctx):
             qs, carry, stats = step(qs, carry, p, ctx)
@@ -714,9 +770,9 @@ class StealRuntime:
 
                 (qs, carry, p, _ctx), tele = lax.scan(
                     body, (qs, carry, p0, ctx0), None, length=k)
-                return qs, carry, p, tele, jnp.int32(k)
+                return qs, carry, p, layout.pack(jnp.int32(k), p, tele)
 
-            return LiftedJit(fused, self._donate_argnums())
+            return FusedProgram(fused, layout, self._donate_argnums())
 
         def fused(qs, carry, p0, ctx0):
             tele_sds = jax.eval_shape(
@@ -739,9 +795,9 @@ class StealRuntime:
 
             qs, carry, p, _ctx, r, tele = lax.while_loop(
                 cond, body, (qs, carry, p0, ctx0, jnp.int32(0), tele0))
-            return qs, carry, p, tele, r
+            return qs, carry, p, layout.pack(r, p, tele)
 
-        return LiftedJit(fused, self._donate_argnums())
+        return FusedProgram(fused, layout, self._donate_argnums())
 
     # -- observability: the phase probe --------------------------------------
 
@@ -965,7 +1021,8 @@ class StealRuntime:
         (:func:`repro.runtime.adaptive.adaptive_update` — the same
         float32 computation the host controller runs, so the trajectory
         is identical), and per-round telemetry is stacked ``(k, ...)``
-        and read back once at the end.
+        and read back, with the round count and the final proportion, in
+        one device-to-host transfer at the end.
 
         With ``until_drained=False`` (default) the block is a
         ``lax.scan`` of exactly ``k`` rounds, returning
@@ -1006,12 +1063,14 @@ class StealRuntime:
                                                            self.rounds_run):
                         self._probe_calibrate(worker_fn, args)
                 t0 = time.perf_counter()
-                self.queues, carry, p_final, tele, rounds = fn(*args)
+                self.queues, carry, _p_final, packed = fn(*args)
             with spans.span("steal:wait"):
-                rounds = int(rounds)
+                # The dispatch's one device-to-host read: it waits for the
+                # block and brings every value the host needs.
+                packed = np.asarray(packed)
+                spans.count("steal.host_reads")
             with spans.span("steal:readback"):
-                # ONE host read-back for the whole fused run.
-                tele = jax.tree_util.tree_map(np.asarray, tele)
+                rounds, p_final, tele = fn.layout.unpack(packed)
             with spans.span("steal:record"):
                 wall_s = time.perf_counter() - t0
                 spans.count("steal.dispatches")
@@ -1025,8 +1084,8 @@ class StealRuntime:
             return carry, stats, rounds
         return carry, stats
 
-    def _record_fused(self, tele, rounds: int, p_final, wall_s: float,
-                      worker_fn, snap, probed: bool):
+    def _record_fused(self, tele, rounds: int, p_final: float,
+                      wall_s: float, worker_fn, snap, probed: bool):
         """Host side of a fused block once its telemetry is read back:
         per-round telemetry records, sanitizer checks, the controller,
         the detector and the snapshot cadence.  Returns the stacked
@@ -1060,8 +1119,7 @@ class StealRuntime:
                  for r in range(rounds)], snap,
                 context=f"StealRuntime.run_fused[{rounds} rounds]")
         if self.controller is not None and rounds > 0:
-            self.controller.absorb(tele["proportion"][:rounds],
-                                   float(p_final))
+            self.controller.absorb(tele["proportion"][:rounds], p_final)
         r0 = self.rounds_run
         self.rounds_run += rounds
         self._feed_detector(r0, rounds, wall_s=wall_s)

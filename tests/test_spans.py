@@ -133,6 +133,18 @@ def test_children_tile_the_dispatch(mode, parent, tmp_path):
     assert snap["counters"]["steal.rounds"] == rt.rounds_run
 
 
+@pytest.mark.parametrize("mode", ["drained", "scan"])
+def test_one_host_read_per_fused_dispatch(mode, tmp_path):
+    """``steal.host_reads`` counts the blocking device-to-host reads of
+    ``run_fused``: one packed buffer a dispatch."""
+    rt = _make_rt()
+    with recording(tmp_path):
+        _, dispatches = _drive(rt, mode)
+    counters = spans.snapshot()["counters"]
+    assert counters["steal.host_reads"] == counters["steal.dispatches"]
+    assert counters["steal.host_reads"] == dispatches
+
+
 @pytest.mark.parametrize("mode", ["drained", "scan", "round"])
 def test_theft_counters_equal_the_telemetry(mode, tmp_path):
     """``steal.thefts`` and ``steal.items_moved`` are the per-round
