@@ -12,8 +12,6 @@
       [--out BENCH_PR8.json]
   PYTHONPATH=src python -m benchmarks.run --chaos [--tiny] \
       [--out BENCH_PR9.json]
-  PYTHONPATH=src python -m benchmarks.run --obs [--tiny] \
-      [--out BENCH_PR10.json]
   PYTHONPATH=src python -m benchmarks.run --check
 
 ``--json`` runs the figures that seed the repo's perf trajectory (Fig. 6
@@ -215,51 +213,6 @@ def run_chaos(out: str, tiny: bool) -> int:
     return 0
 
 
-def run_obs(out: str, tiny: bool) -> int:
-    # The mesh phase-breakdown mode needs one fake host device per lane;
-    # claim them inline BEFORE jax initializes (the run_mesh discipline).
-    import os
-
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8").strip()
-
-    import jax
-
-    from benchmarks import obs_overhead
-
-    t0 = time.time()
-    table, data = obs_overhead.run(tiny=tiny)
-    table.show()
-    bt, breakdown = obs_overhead.phase_breakdown(tiny=tiny)
-    bt.show()
-    data["phase_breakdown"] = breakdown
-    results = {
-        "meta": {
-            "bench": "BENCH_PR10",
-            "backend": jax.default_backend(),
-            "jax": jax.__version__,
-            "python": platform.python_version(),
-            "tiny": tiny,
-            "wall_s": time.time() - t0,
-        },
-        "obs_overhead": data,
-    }
-    with open(out, "w") as f:
-        json.dump(results, f, indent=1)
-    print(f"[benchmarks] wrote {out} "
-          f"(probe overhead {data['probe_overhead']:.3f}x "
-          f"< {data['overhead_limit']:g}x, gates_ok {data['gates_ok']}, "
-          f"modes {sorted(breakdown)}, "
-          f"{results['meta']['wall_s']:.1f}s)")
-    if not data["gates_ok"]:
-        failed = [g for g, ok in data["gates"].items() if not ok]
-        print(f"[benchmarks] FAILED obs gates: {failed}", file=sys.stderr)
-        return 1
-    return 0
-
-
 def run_adaptive_sweep(out: str, tiny: bool) -> int:
     import jax
 
@@ -348,11 +301,6 @@ def main():
                          "seeded kill/delay/drop drains (flat and 2x4 "
                          "pods), detector delay->kill conversion, live "
                          "no-rebuild resize -> BENCH_PR9.json")
-    ap.add_argument("--obs", action="store_true",
-                    help="observability gates: phase-probe overhead on the "
-                         "fused Fig. 9 drain (< 5%%, bit-identical, zero "
-                         "compiles when off) + host/vmap/mesh per-phase "
-                         "wall split -> BENCH_PR10.json")
     ap.add_argument("--check", action="store_true",
                     help="tiny Fig. 9 smoke under the conservation "
                          "sanitizer (REPRO_CHECK=1); fails on any "
@@ -368,8 +316,6 @@ def main():
         os.path.abspath(__file__))))
     if args.check:
         return run_check()
-    if args.obs:
-        return run_obs(args.out or "BENCH_PR10.json", args.tiny)
     if args.chaos:
         return run_chaos(args.out or "BENCH_PR9.json", args.tiny)
     if args.serve:

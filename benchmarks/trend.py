@@ -26,12 +26,11 @@ runs that produce them).
 CLI::
 
   PYTHONPATH=src python -m benchmarks.trend [--dir .] \
-      [--current BENCH_PR10.json ...] [--report trend_report.json]
+      [--current BENCH_NEW.json ...] [--report trend_report.json]
 
-``--current`` appends freshly-produced files as the newest points (the
-CI obs lane passes the run's own output); without it, the newest
-checked-in file per metric is judged.  Exits 1 on any regression —
-this is the perf gate, wired into CI next to the test lanes.
+``--current`` appends freshly-produced files as the newest points;
+without it, the newest checked-in file per metric is judged.  Exits 1
+on any regression.
 """
 
 from __future__ import annotations
@@ -93,7 +92,8 @@ METRICS: Dict[str, MetricSpec] = {
     "chaos_armed_overhead": MetricSpec(
         ("chaos_recovery", "armed_overhead", "armed flat", "overhead"),
         kind="lower", tolerance=0.5, ceiling=2.0),
-    # BENCH_PR10: the phase probe's hard overhead budget + gate bundle.
+    # BENCH_PR10 schema: the retired phase probe's overhead budget +
+    # gate bundle (no such file is checked in any more; ROADMAP Design 7).
     "obs_probe_overhead": MetricSpec(("obs_overhead", "probe_overhead"),
                                      kind="lower", tolerance=0.5,
                                      ceiling=1.05),

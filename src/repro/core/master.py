@@ -66,7 +66,7 @@ from repro.core.ops import QueueState
 from repro.core.policy import StealPolicy, plan_transfers
 
 __all__ = ["RebalanceStats", "superstep", "hierarchical_superstep",
-           "gather_sizes", "exchange_probe", "probe_token"]
+           "gather_sizes"]
 
 Pytree = Any
 
@@ -338,69 +338,6 @@ def superstep(
         bytes_moved_xpod=jnp.int32(0),
     )
     return q, stats
-
-
-def probe_token(q: QueueState) -> jnp.ndarray:
-    """Collapse a queue into one float32 scalar that data-depends on its
-    cursors AND its buffer contents — the phase probe's anti-DCE sink
-    (XLA cannot eliminate work whose result feeds the returned token).
-    One element per ring leaf is enough: a collective or a splice cannot
-    be partially computed, so keeping any element live keeps the whole
-    producing op live."""
-    token = q.size.astype(jnp.float32) + q.lo.astype(jnp.float32)
-    for leaf in jax.tree_util.tree_leaves(q.buf):
-        token = token + leaf.reshape(-1)[0].astype(jnp.float32)
-    return token
-
-
-def exchange_probe(
-    q: QueueState,
-    policy: StealPolicy,
-    *,
-    axis_name: str,
-    ops: bulk_ops.BulkOps | None = None,
-    exchange: str | None = None,
-    plan: jnp.ndarray | None = None,
-) -> jnp.ndarray:
-    """The superstep's gather + plan + block-exchange PREFIX, reduced to one
-    DCE-proof float32 token — the phase probe's "exchange" programs
-    (``repro.obs.phase``) end here.
-
-    Runs the exact collective schedule :func:`superstep` runs up to and
-    including the block exchange (same size gather, same replicated plan,
-    same compact fast path / dense outbox), then collapses the resulting
-    queue into a scalar that data-depends on the spliced buffer contents
-    and the moved cursors, so XLA cannot dead-code-eliminate any of the
-    exchange work.  The queue itself is discarded — callers time this
-    program on immutable inputs and throw the result away; it never
-    commits state.  Stats, the sanitizer hook and the post-exchange size
-    gather are deliberately omitted: those belong to the ``splice``/
-    bookkeeping tail the probe attributes by subtraction.
-    """
-    if ops is None:
-        ops = _resolve_ops(policy, q)
-    if exchange is None:
-        exchange = policy.exchange
-    n_workers = lax.psum(1, axis_name)
-    me = lax.axis_index(axis_name)
-    idx = jnp.arange(n_workers, dtype=jnp.int32)
-
-    sizes = lax.all_gather(q.size, axis_name)
-    if plan is None:
-        plan = plan_transfers(sizes, policy)
-    src, amt = plan[:, 0], plan[:, 1]
-
-    if exchange == "dense":
-        q, _ = _dense_exchange(q, ops, policy, axis_name,
-                               n_workers, me, idx, src, amt)
-    elif exchange == "compact":
-        q, _ = _compact_exchange(q, ops, policy, axis_name,
-                                 me, idx, sizes, src, amt)
-    else:
-        raise ValueError(
-            f"unknown exchange {exchange!r}; expected 'compact' or 'dense'")
-
-    return probe_token(q)
 
 
 def hierarchical_superstep(

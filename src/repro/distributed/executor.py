@@ -138,17 +138,14 @@ class MeshStealRuntime(StealRuntime):
     def _axes_tuple(self) -> tuple:
         return tuple(self.mesh.axis_names)
 
-    def _make_step(self, worker_fn: Optional[WorkerFn],
-                   stage: Optional[str] = None) -> Callable:
+    def _make_step(self, worker_fn: Optional[WorkerFn]) -> Callable:
         """Un-jitted ``(qs, carry, proportion, ctx) -> (qs, carry,
         stats)``, identical signature and output layout to the vmapped
         runtime's — but each lane executes on its own device and the
         stats come back gathered into the stacked ``(W, ...)`` lane
         order.  The fault context is replicated (the schedule is the
-        virtual master's view, identical on every device).  A non-None
-        ``stage`` builds the phase probe's truncated prefix (the stats
-        slot is then the per-lane scalar token, gathered to ``(W,)``)."""
-        lane_fn = self._lane_step(worker_fn, stage)
+        virtual master's view, identical on every device)."""
+        lane_fn = self._lane_step(worker_fn)
         lane = self._lane_spec
         ctx_spec = resilience.ctx_specs(self.fault is not None)
 

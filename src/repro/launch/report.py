@@ -1,18 +1,12 @@
 """Generate the EXPERIMENTS.md §Dry-run and §Roofline sections from the
-dry-run JSON, plus the §Observability section from the obs gate bench.
+dry-run JSON.
 
-  PYTHONPATH=src python -m repro.launch.report results/dryrun.json \
-      [BENCH_PR10.json]
-
-The observability section renders only when its BENCH file exists
-(second argument, default ``BENCH_PR10.json``) — per-phase wall split
-across execution modes and the probe-contract gate results.
+  PYTHONPATH=src python -m repro.launch.report results/dryrun.json
 """
 
 from __future__ import annotations
 
 import json
-import os
 import sys
 from typing import List
 
@@ -98,37 +92,6 @@ def roofline_section(results: List[dict]) -> str:
     return "\n".join(lines)
 
 
-def obs_section(bench: dict) -> str:
-    """§Observability from a BENCH_PR10-schema dict (benchmarks/run.py
-    --obs): the phase-probe gate results and the per-phase wall split
-    per execution mode."""
-    obs = bench["obs_overhead"]
-    lines = [
-        "## §Observability",
-        "",
-        "Phase-probe contract on the fused Fig. 9 drain (DESIGN.md §11):",
-        "overhead is the median paired probed/unprobed wall ratio;",
-        "bit-identity and compile-identity are exact checks.",
-        "",
-        f"- probe overhead {obs['probe_overhead']:.3f}x"
-        f" (budget < {obs['overhead_limit']:g}x)"
-        f" — gates {'ALL PASS' if obs['gates_ok'] else 'FAILING'}:"
-        f" {', '.join(k for k, v in obs['gates'].items() if not v) or 'none failing'}",
-        "",
-        "| mode | rounds | worker_body | exchange | splice | adaptive |",
-        "|---|---|---|---|---|---|",
-    ]
-    for mode, d in obs.get("phase_breakdown", {}).items():
-        fr = d.get("phases", {})
-        cells = " | ".join(
-            f"{fr[p]['fraction']:.0%}" if p in fr else "-"
-            for p in ("worker_body", "exchange", "splice",
-                      "adaptive_update"))
-        lines.append(f"| {mode} | {d['timed_rounds']} | {cells} |")
-    lines.append("")
-    return "\n".join(lines)
-
-
 def main():
     path = sys.argv[1] if len(sys.argv) > 1 else "results/dryrun.json"
     with open(path) as f:
@@ -136,11 +99,6 @@ def main():
     print(dryrun_section(results))
     print()
     print(roofline_section(results))
-    obs_path = sys.argv[2] if len(sys.argv) > 2 else "BENCH_PR10.json"
-    if os.path.exists(obs_path):
-        with open(obs_path) as f:
-            print()
-            print(obs_section(json.load(f)))
 
 
 if __name__ == "__main__":

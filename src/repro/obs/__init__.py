@@ -1,18 +1,14 @@
 """repro.obs — observability for the steal runtime.
 
-Five cooperating pieces (DESIGN.md §11):
+Four cooperating pieces (DESIGN.md §11):
 
 * :mod:`repro.obs.spans` — host spans and counters of the runtime's
   dispatch loop and per-call set-up, recorded only while a
   ``jax.profiler`` session records, on the profiler's clock.
-* :mod:`repro.obs.phase` — per-round wall-clock attributed to
-  ``worker_body`` / ``exchange`` / ``splice`` / ``adaptive_update`` via
-  truncated-prefix re-execution (off by default; compile-identical and
-  bit-identical when off).
 * :mod:`repro.obs.trace` — Chrome-trace/Perfetto JSON export of one
-  :class:`~repro.runtime.telemetry.Telemetry` stream: round spans with
-  phase children, wave spans, per-request flows, fault/detector instant
-  events on one timeline.
+  :class:`~repro.runtime.telemetry.Telemetry` stream: round spans, wave
+  spans, per-request flows, fault/detector instant events on one
+  timeline.
 * :mod:`repro.obs.metrics` — counter/gauge/histogram registry with
   Prometheus text exposition and JSON snapshots, fed by the telemetry,
   the failure detector, both admission masters and PagedQueue spill
@@ -24,9 +20,7 @@ Five cooperating pieces (DESIGN.md §11):
 
 from repro.obs.metrics import (MetricsRegistry, master_metrics,  # noqa: F401
                                runtime_metrics)
-from repro.obs.phase import PhaseProbe, PhaseSample  # noqa: F401
 from repro.obs.trace import export_trace, validate_trace  # noqa: F401
 
-__all__ = ["PhaseProbe", "PhaseSample", "MetricsRegistry",
-           "runtime_metrics", "master_metrics", "export_trace",
-           "validate_trace"]
+__all__ = ["MetricsRegistry", "runtime_metrics", "master_metrics",
+           "export_trace", "validate_trace"]

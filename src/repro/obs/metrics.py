@@ -218,8 +218,7 @@ def collect_telemetry(reg: MetricsRegistry, tele,
                       prefix: str = "repro") -> MetricsRegistry:
     """Read one :class:`~repro.runtime.telemetry.Telemetry` stream into
     ``reg``: lifetime round totals, the adaptive trajectory endpoints,
-    wave/request SLO aggregates, fault-event counters and — on probed
-    runs — the per-phase wall-clock attribution."""
+    wave/request SLO aggregates and fault-event counters."""
     s = tele.summary()
     reg.counter(f"{prefix}_rounds_total",
                 "rebalancing rounds recorded").set_total(s["rounds"])
@@ -260,21 +259,6 @@ def collect_telemetry(reg: MetricsRegistry, tele,
                             "admit->finish latency per request, in rounds")
         for r in tele.requests:
             lat.observe(r.latency)
-    ps = tele.phase_summary()
-    if ps["timed_rounds"]:
-        reg.counter(f"{prefix}_phase_timed_rounds_total",
-                    "rounds with phase attribution").set_total(
-                        ps["timed_rounds"])
-        reg.counter(f"{prefix}_phase_estimated_rounds_total",
-                    "attributed rounds using calibrated estimates"
-                    ).set_total(ps["estimated_rounds"])
-        sec = reg.counter(f"{prefix}_phase_seconds_total",
-                          "attributed wall seconds by round phase")
-        frac = reg.gauge(f"{prefix}_phase_fraction",
-                         "share of attributed wall by round phase")
-        for name, agg in ps["phases"].items():
-            sec.set_total(agg["total_s"], phase=name)
-            frac.set(agg["fraction"], phase=name)
     return reg
 
 

@@ -1,29 +1,26 @@
 """Chrome-trace / Perfetto export of one :class:`Telemetry` stream.
 
 One telemetry object already carries the whole story of a run on a
-single logical-round timeline: the master's rounds (with per-phase wall
-splits when the probe was armed), the workload's waves, every served
-request's admit -> first-token -> finish stamps, and the round-stamped
-fault/detector event log.  This module renders that stream as standard
-`Trace Event Format`_ JSON — load the file in ``chrome://tracing``,
-https://ui.perfetto.dev or ``about:tracing`` and the rounds, phases,
-waves, requests and failures line up on one zoomable timeline.
+single logical-round timeline: the master's rounds, the workload's
+waves, every served request's admit -> first-token -> finish stamps,
+and the round-stamped fault/detector event log.  This module renders
+that stream as standard `Trace Event Format`_ JSON — load the file in
+``chrome://tracing``, https://ui.perfetto.dev or ``about:tracing`` and
+the rounds, waves, requests and failures line up on one zoomable
+timeline.
 
 The clock is LOGICAL: one round occupies ``round_us`` microseconds of
 trace time (default 1000 us = 1 ms per round), so traces from host,
 vmap and mesh runs of the same schedule align event-for-event and are
-directly diffable.  Within a probed round the phase children scale the
-round span by their MEASURED fractions — so the picture shows real
-relative cost (where did the round's wall go) on the deterministic
-round grid.  Unprobed rounds render as bare round spans.
+directly diffable.  Where a round's wall-clock goes is not on this
+clock: the host side is in :mod:`repro.obs.spans`, the device side in
+the named scopes of a ``jax.profiler`` trace (DESIGN.md §11).
 
 Emitted events (all standard phases, no extensions):
 
 * ``X`` complete spans, pid 0 / tid 0: one ``round N`` per
-  :class:`RoundRecord`, with nested ``worker_body`` / ``exchange`` /
-  ``splice`` / ``adaptive_update`` children when the record is
-  phase-timed (args carry steals, items moved, proportion, imbalance,
-  and whether the split was estimated).
+  :class:`RoundRecord` (args carry steals, items moved, proportion,
+  imbalance).
 * ``X`` spans, pid 0 / tid 1: one ``wave N`` per :class:`WaveRecord`,
   spanning from the previous wave's closing round to its own (args:
   served, tokens, loads, SLO percentiles).
@@ -54,20 +51,16 @@ _TID_ROUNDS = 0
 _TID_WAVES = 1
 _TID_REQUESTS = 2
 
-# Phase child order must match repro.obs.phase.PHASES.
-_PHASE_FIELDS = (("worker_body", "t_worker"), ("exchange", "t_exchange"),
-                 ("splice", "t_splice"), ("adaptive_update", "t_adaptive"))
-
 
 def _meta(name: str, tid: int, label: str) -> Dict[str, Any]:
     return {"ph": "M", "pid": _PID, "tid": tid, "name": name,
             "args": {"name": label}}
 
 
-def _round_events(rec, round_us: float) -> List[Dict[str, Any]]:
-    ts = rec.round * round_us
-    events: List[Dict[str, Any]] = [{
-        "ph": "X", "pid": _PID, "tid": _TID_ROUNDS, "ts": ts,
+def _round_event(rec, round_us: float) -> Dict[str, Any]:
+    return {
+        "ph": "X", "pid": _PID, "tid": _TID_ROUNDS,
+        "ts": rec.round * round_us,
         "dur": round_us, "name": f"round {rec.round}", "cat": "round",
         "args": {
             "n_steals": rec.n_steals,
@@ -77,23 +70,7 @@ def _round_events(rec, round_us: float) -> List[Dict[str, Any]]:
             "imbalance": rec.imbalance,
             "sizes_total": rec.sizes_total,
         },
-    }]
-    if not rec.phase_timed:
-        return events
-    events[0]["args"]["t_round_s"] = rec.t_round
-    events[0]["args"]["phase_estimated"] = rec.phase_estimated
-    total = rec.t_round or 1.0
-    cursor = ts
-    for name, field in _PHASE_FIELDS:
-        dur = round_us * (getattr(rec, field) / total)
-        events.append({
-            "ph": "X", "pid": _PID, "tid": _TID_ROUNDS, "ts": cursor,
-            "dur": dur, "name": name, "cat": "phase",
-            "args": {"seconds": getattr(rec, field),
-                     "estimated": rec.phase_estimated},
-        })
-        cursor += dur
-    return events
+    }
 
 
 def _wave_events(telemetry: Telemetry, round_us: float
@@ -164,15 +141,13 @@ def export_trace(telemetry: Telemetry, path: Optional[str] = None, *,
         _meta("thread_name", _TID_WAVES, "waves"),
         _meta("thread_name", _TID_REQUESTS, "requests"),
     ]
-    for rec in telemetry.rounds:
-        events.extend(_round_events(rec, round_us))
+    events.extend(_round_event(rec, round_us) for rec in telemetry.rounds)
     events.extend(_wave_events(telemetry, round_us))
     events.extend(_request_events(telemetry, round_us))
     events.extend(_fault_events(telemetry, round_us))
     trace = {"traceEvents": events, "displayTimeUnit": "ms",
              "otherData": {"clock": f"logical ({round_us} us per round)",
-                           "summary": telemetry.summary(),
-                           "phase_summary": telemetry.phase_summary()}}
+                           "summary": telemetry.summary()}}
     if path is not None:
         with open(path, "w") as f:
             json.dump(trace, f)
@@ -225,7 +200,7 @@ def validate_trace(trace: Dict[str, Any]) -> Dict[str, int]:
 
 def _smoke_telemetry() -> Telemetry:
     """A deterministic miniature of the full story in one telemetry
-    stream: a 4-lane probed chaos drain (scheduled straggler window the
+    stream: a 4-lane chaos drain (scheduled straggler window the
     detector converts into suspects, a scheduled kill, a live revive)
     with serve-style wave + request records layered on the same round
     clock."""
@@ -245,7 +220,6 @@ def _smoke_telemetry() -> Telemetry:
         # dies at round 6 (-> recovery superstep drains its ring).
         fault_plan=FaultPlan(kills=((3, 6),), delays=((1, 2, 3),)))
     rt.attach_detector(DetectorPolicy(suspect_after=2, dead_after=None))
-    rt.attach_phase_probe(calibrate_every=4)
     # All work starts on lane 0: the drain IS the rebalance.
     rt.push(0, {"x": jnp.arange(items, dtype=jnp.int32)}, items)
 
@@ -255,8 +229,8 @@ def _smoke_telemetry() -> Telemetry:
 
     admitted: List[int] = []
     for tick in range(6):
-        rt.round(body)               # unfused: direct phase measurement
-        rt.run_fused(2, body)        # fused: calibrated estimate
+        rt.round(body)               # one round per dispatch
+        rt.run_fused(2, body)        # two rounds in one dispatch
         if tick == 2:
             rt.revive_lane(3)
         # Serve layer on the same stream: admit one request per tick,

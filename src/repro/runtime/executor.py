@@ -182,8 +182,7 @@ def make_lane_step(policy: StealPolicy, ops: bulk_ops.BulkOps,
                    worker_fn: Optional[WorkerFn], *, axis_name: str,
                    pod_axis: Optional[str] = None,
                    hierarchical: bool = False,
-                   fault: bool = False,
-                   stage: Optional[str] = None) -> Callable:
+                   fault: bool = False) -> Callable:
     """The mode-agnostic round body for ONE lane:
     ``(q, carry, proportion, ctx) -> (q, carry, stats)``.
 
@@ -207,41 +206,23 @@ def make_lane_step(policy: StealPolicy, ops: bulk_ops.BulkOps,
     runs the dead-ring recovery superstep each round (intra-pod recovery
     plus the cross-pod dead-POD escalation when ``hierarchical=True``).
 
-    ``stage`` selects a TRUNCATED PREFIX of the round for the phase
-    probe (:mod:`repro.obs.phase`) — ``None`` (the default, the only
-    value production dispatches ever use) is the full round above;
-    ``"worker"`` stops after the worker body; ``"exchange"`` stops after
-    the block-exchange collective (:func:`repro.core.master.
-    exchange_probe`).  Prefix lanes return ``(q, carry, token)`` with a
-    DCE-proof scalar token in the stats slot; they are compiled into a
-    SEPARATE jit cache, run on immutable inputs, and their results are
-    discarded — timing them and subtracting attributes wall-clock to
-    ``worker_body`` / ``exchange`` / ``splice`` without touching the
-    committed round.  On the hierarchical grid ``"exchange"`` covers the
-    intra-pod exchange only (the cross-pod level folds into the splice
-    share — documented in DESIGN.md §11).
+    Each phase of the round runs under a named scope (``worker_body``,
+    ``master.sizes|plan|exchange|splice``, and ``queue.*`` inside the
+    queue ops), so a device trace attributes each instruction's time to
+    its phase (DESIGN.md §11).
     """
-    if stage not in (None, "worker", "exchange"):
-        raise ValueError(f"unknown stage {stage!r}")
     if fault:
         return resilience.make_resilient_lane(policy, ops, worker_fn,
                                               axis_name=axis_name,
                                               pod_axis=pod_axis,
-                                              hierarchical=hierarchical,
-                                              stage=stage)
+                                              hierarchical=hierarchical)
 
     def lane(q, carry, proportion, ctx):
         del ctx  # round index only; same signature as the fault lane
         if worker_fn is not None:
             with jax.named_scope("worker_body"):
                 q, carry = worker_fn(q, carry)
-        if stage == "worker":
-            return q, carry, master_ops.probe_token(q)
         pol = dataclasses.replace(policy, proportion=proportion)
-        if stage == "exchange":
-            token = master_ops.exchange_probe(q, pol, axis_name=axis_name,
-                                              ops=ops)
-            return q, carry, token
         if hierarchical:
             q, stats = master_ops.hierarchical_superstep(
                 q, pol, worker_axis=axis_name, pod_axis=pod_axis, ops=ops)
@@ -351,12 +332,6 @@ class StealRuntime:
         self.rounds_run = 0
         self._compiled: Dict[Any, Callable] = (
             {} if programs is None else programs)
-        # Phase probe (repro.obs.phase): truncated-prefix programs live
-        # in their OWN cache so elastic.compile_count — which audits
-        # ``_compiled`` as the zero-recompile gate — never sees them.
-        self._phase_probe = None
-        self._probe_compiled: Dict[Any, Callable] = {}
-        self._probe_warmed: set = set()
         # Resilience: the host-side fault schedule (None = machinery off,
         # zero trace-structure change) and the snapshot cadence.
         if fault_plan is not None:
@@ -673,15 +648,13 @@ class StealRuntime:
 
     # -- the round -----------------------------------------------------------
 
-    def _lane_step(self, worker_fn: Optional[WorkerFn],
-                   stage: Optional[str] = None) -> Callable:
+    def _lane_step(self, worker_fn: Optional[WorkerFn]) -> Callable:
         """The shared one-lane round body (see :func:`make_lane_step`)."""
         return make_lane_step(self.policy, self.ops, worker_fn,
                               axis_name=self.axis_name,
                               pod_axis=self.pod_axis,
                               hierarchical=self.pod_size is not None,
-                              fault=self.fault is not None,
-                              stage=stage)
+                              fault=self.fault is not None)
 
     def _ctx(self, round0: int):
         """The fault context for a dispatch starting at global round
@@ -692,14 +665,11 @@ class StealRuntime:
             return self.fault.ctx(round0)
         return jnp.int32(round0)
 
-    def _make_step(self, worker_fn: Optional[WorkerFn],
-                   stage: Optional[str] = None) -> Callable:
-        """Un-jitted ``(qs, carry, proportion, ctx) -> (qs, carry, stats)``.
-        A non-None ``stage`` builds the phase probe's truncated prefix of
-        the same round (stats slot holds the DCE-proof token)."""
+    def _make_step(self, worker_fn: Optional[WorkerFn]) -> Callable:
+        """Un-jitted ``(qs, carry, proportion, ctx) -> (qs, carry, stats)``."""
         pod_size = self.pod_size
         axis_name, pod_axis = self.axis_name, self.pod_axis
-        lane = self._lane_step(worker_fn, stage)
+        lane = self._lane_step(worker_fn)
 
         if pod_size is None:
             mapped = jax.vmap(lane, axis_name=axis_name,
@@ -799,106 +769,17 @@ class StealRuntime:
 
         return FusedProgram(fused, layout, self._donate_argnums())
 
-    # -- observability: the phase probe --------------------------------------
-
-    def attach_phase_probe(self, probe=None, **kwargs):
-        """Arm per-round phase attribution (:mod:`repro.obs.phase`):
-        subsequent :meth:`round` dispatches time the worker/exchange
-        prefix programs directly, :meth:`run_fused` blocks split their
-        wall by calibrated fractions, and every
-        :class:`~repro.runtime.telemetry.RoundRecord` gains the
-        ``t_worker``/``t_exchange``/``t_splice``/``t_adaptive`` fields
-        (``Telemetry.phase_summary()`` aggregates them).  Pass an
-        existing :class:`~repro.obs.phase.PhaseProbe` or constructor
-        kwargs (``enabled=``, ``calibrate_every=``).  Returns the probe
-        (also at ``_phase_probe``); set ``probe.enabled = False`` to
-        disarm without losing calibrations — the dispatch path is then
-        byte-identical to an unprobed runtime."""
-        from repro.obs.phase import PhaseProbe
-
-        if probe is None:
-            probe = PhaseProbe(**kwargs)
-        self._phase_probe = probe
-        return probe
-
-    def _probe_enabled(self) -> bool:
-        return self._phase_probe is not None and self._phase_probe.enabled
+    # -- observability -------------------------------------------------------
 
     def metrics(self, registry=None):
         """Poll this runtime into a :class:`repro.obs.metrics.
         MetricsRegistry` (queue depths, steal totals, fault/detector
-        census, phase attribution when probed).  Pull-style and
-        side-effect free — call it mid-run at any cadence;
+        census).  Pull-style and side-effect free — call it mid-run at
+        any cadence;
         ``registry.to_prometheus()`` / ``.snapshot()`` render it."""
         from repro.obs.metrics import runtime_metrics
 
         return runtime_metrics(self, registry)
-
-    def _probe_fn(self, worker_fn: Optional[WorkerFn],
-                  stage: str) -> Callable:
-        """The jitted probe program for one stage: ``"worker"`` /
-        ``"exchange"`` truncated prefixes, ``"full"`` the complete round
-        re-jitted WITHOUT donation (pure — timing it must not invalidate
-        the committed inputs), ``"adaptive"`` the full round plus the
-        on-device proportion update (so the calibration sees the same
-        adaptive arithmetic the fused carry runs)."""
-        key = (worker_fn, stage)
-        fn = self._probe_compiled.get(key)
-        if fn is not None:
-            return fn
-        if stage in ("worker", "exchange"):
-            fn = jax.jit(self._make_step(worker_fn, stage=stage))
-        elif stage == "full":
-            fn = jax.jit(self._make_step(worker_fn))
-        elif stage == "adaptive":
-            step = self._make_step(worker_fn)
-            policy, controller = self.policy, self.controller
-            config = controller.config if controller else None
-
-            def step_a(qs, carry, p, ctx):
-                qs, carry, stats = step(qs, carry, p, ctx)
-                sizes = resilience.mask_sizes(
-                    qs.size, resilience.ctx_advance(ctx), policy)
-                p2 = adaptive_update(p, sizes, policy=policy, config=config)
-                return qs, carry, stats, p2
-
-            fn = jax.jit(step_a)
-        else:
-            raise ValueError(f"unknown probe stage {stage!r}")
-        self._probe_compiled[key] = fn
-        return fn
-
-    def _probe_time(self, worker_fn: Optional[WorkerFn], stage: str,
-                    args) -> float:
-        """Wall seconds of one probe program on ``args`` (result
-        discarded).  The first call per (worker_fn, stage) runs once
-        untimed so compilation never pollutes a measurement."""
-        from repro.obs.phase import timed_call
-
-        key = (worker_fn, stage)
-        fn = self._probe_fn(worker_fn, stage)
-        if key not in self._probe_warmed:
-            jax.block_until_ready(fn(*args))
-            self._probe_warmed.add(key)
-        t, _ = timed_call(fn, args)
-        return t
-
-    def _probe_calibrate(self, worker_fn: Optional[WorkerFn], args) -> None:
-        """Refresh the fused-attribution fractions for ``worker_fn`` by
-        timing the four probe programs on the current state (pure, all
-        results discarded)."""
-        t_worker = self._probe_time(worker_fn, "worker", args)
-        t_exchange = self._probe_time(worker_fn, "exchange", args)
-        t_full = self._probe_time(worker_fn, "full", args)
-        if self.controller is not None:
-            t_adaptive = self._probe_time(worker_fn, "adaptive", args)
-        else:
-            t_adaptive = t_full
-        self._phase_probe.store_calibration(
-            worker_fn,
-            (t_worker, t_exchange - t_worker, t_full - t_exchange,
-             t_adaptive - t_full),
-            self.rounds_run)
 
     def _round_counts(self, stats) -> Tuple[int, int, int]:
         """Exact (n_steals, n_transferred, bytes_moved) for one round's
@@ -957,24 +838,11 @@ class StealRuntime:
                     carry = jnp.zeros((self.n_workers,), jnp.int32)
                 snap = self._pre_dispatch_snapshot(worker_fn)
                 proportion = self.proportion
-                probed = self._probe_enabled()
                 args = (self.queues, carry, jnp.float32(proportion),
                         self._ctx(self.rounds_run))
-                t_worker = t_exchange = 0.0
-                if probed:
-                    # Direct attribution: time the worker and exchange
-                    # PREFIX programs on the immutable inputs the
-                    # committed round is about to consume (pure, results
-                    # discarded), then fence the unchanged full round.
-                    jax.block_until_ready(args)
-                    t_worker = self._probe_time(worker_fn, "worker", args)
-                    t_exchange = self._probe_time(worker_fn, "exchange",
-                                                  args)
                 t0 = time.perf_counter()
                 self.queues, carry, stats = fn(*args)
             with spans.span("steal:wait"):
-                if probed:
-                    jax.block_until_ready((self.queues, carry, stats))
                 sizes = self.sizes()
                 wall_s = time.perf_counter() - t0
             with spans.span("steal:readback"):
@@ -989,20 +857,12 @@ class StealRuntime:
                     self._post_dispatch_checks(
                         [jax.tree_util.tree_map(np.asarray, stats)], snap,
                         context="StealRuntime.round")
-                t_a0 = time.perf_counter()
                 if self.controller is not None:
                     self.controller.update(self._controller_sizes(sizes))
-                phases = None
-                if probed:
-                    phases = self._phase_probe.direct_sample(
-                        t_worker=t_worker, t_exchange=t_exchange,
-                        t_full=wall_s,
-                        t_adaptive=time.perf_counter() - t_a0).as_record()
                 self.telemetry.record(sizes=sizes, n_steals=n_steals,
                                       n_transferred=n_transferred,
                                       proportion=proportion,
-                                      bytes_moved=bytes_moved,
-                                      phases=phases)
+                                      bytes_moved=bytes_moved)
                 r0 = self.rounds_run
                 self.rounds_run += 1
                 self._feed_detector(r0, 1, wall_s=wall_s)
@@ -1051,17 +911,7 @@ class StealRuntime:
                     carry = jnp.zeros((self.n_workers,), jnp.int32)
                 snap = self._pre_dispatch_snapshot(worker_fn)
                 p0 = jnp.float32(self.proportion)
-                probed = self._probe_enabled()
                 args = (self.queues, carry, p0, self._ctx(self.rounds_run))
-                if probed:
-                    # Calibrated attribution: refresh the phase fractions
-                    # on the current state when stale (four pure prefix
-                    # dispatches per calibrate_every rounds), fence, then
-                    # time the one real dispatch end to end.
-                    jax.block_until_ready(args)
-                    if self._phase_probe.needs_calibration(worker_fn,
-                                                           self.rounds_run):
-                        self._probe_calibrate(worker_fn, args)
                 t0 = time.perf_counter()
                 self.queues, carry, _p_final, packed = fn(*args)
             with spans.span("steal:wait"):
@@ -1076,7 +926,7 @@ class StealRuntime:
                 spans.count("steal.dispatches")
                 spans.count("steal.rounds", rounds)
                 stats = self._record_fused(tele, rounds, p_final, wall_s,
-                                           worker_fn, snap, probed)
+                                           snap)
                 if until_drained:
                     stats = jax.tree_util.tree_map(lambda x: x[:rounds],
                                                    stats)
@@ -1085,20 +935,12 @@ class StealRuntime:
         return carry, stats
 
     def _record_fused(self, tele, rounds: int, p_final: float,
-                      wall_s: float, worker_fn, snap, probed: bool):
+                      wall_s: float, snap):
         """Host side of a fused block once its telemetry is read back:
         per-round telemetry records, sanitizer checks, the controller,
         the detector and the snapshot cadence.  Returns the stacked
         per-round stats."""
         stats = tele["stats"]
-        per_round_s = wall_s / rounds if rounds > 0 else 0.0
-        phases = None
-        if probed and rounds > 0:
-            # One sample reused for every round of the block — the split
-            # is the same cached fractions either way, and ``record``
-            # copies the values out.
-            phases = self._phase_probe.estimated_sample(
-                worker_fn, per_round_s, n=rounds).as_record()
         thefts = moved = 0
         for r in range(rounds):
             stats_r = jax.tree_util.tree_map(lambda x: x[r], stats)
@@ -1107,8 +949,7 @@ class StealRuntime:
                                   n_steals=n_steals,
                                   n_transferred=n_transferred,
                                   proportion=float(tele["proportion"][r]),
-                                  bytes_moved=bytes_moved,
-                                  phases=phases)
+                                  bytes_moved=bytes_moved)
             thefts += n_steals
             moved += n_transferred
         spans.count("steal.thefts", thefts)

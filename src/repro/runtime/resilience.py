@@ -327,8 +327,7 @@ def _select(keep_old: jnp.ndarray, old: Pytree, new: Pytree) -> Pytree:
 
 def make_resilient_lane(policy: StealPolicy, ops, worker_fn, *,
                         axis_name: str, pod_axis: Optional[str] = None,
-                        hierarchical: bool = False,
-                        stage: Optional[str] = None):
+                        hierarchical: bool = False):
     """The fault-injecting round body for ONE lane:
     ``(q, carry, proportion, ctx) -> (q, carry, stats)`` — what
     :func:`repro.runtime.executor.make_lane_step` returns when the
@@ -365,17 +364,11 @@ def make_resilient_lane(policy: StealPolicy, ops, worker_fn, *,
     :func:`repro.runtime.telemetry.reduce_round_stats` accounting
     convention: xpod counters nonzero only at lane ``(p, 0)``.
 
-    ``stage`` truncates the lane for the phase probe exactly as in
-    :func:`~repro.runtime.executor.make_lane_step`: ``"worker"`` stops
-    after the (skip-masked) worker body, ``"exchange"`` after the normal
-    block exchange with the SAME dead-masked plan the full round uses
-    (the recovery supersteps belong to the splice share).  Prefix lanes
-    return a DCE-proof scalar token in the stats slot and never commit
-    state."""
+    The plans are computed here, not in :func:`~repro.core.master.
+    superstep`, so they run under this lane's own ``master.plan`` scope
+    (the name the device trace attributes plan time to)."""
     if hierarchical and pod_axis is None:
         raise ValueError("hierarchical resilient lane needs a pod_axis")
-    if stage not in (None, "worker", "exchange"):
-        raise ValueError(f"unknown stage {stage!r}")
 
     def flat_lane(q, carry, proportion, ctx):
         r = ctx_round(ctx)
@@ -389,8 +382,6 @@ def make_resilient_lane(policy: StealPolicy, ops, worker_fn, *,
             skip = i_am_dead | i_am_delayed
             q = _select(skip, q, q_new)
             carry = _select(skip, carry, carry_new)
-        if stage == "worker":
-            return q, carry, master_ops.probe_token(q)
 
         pol = dataclasses.replace(policy, proportion=proportion)
         cap = jax.tree_util.tree_leaves(q.buf)[0].shape[0]
@@ -399,21 +390,19 @@ def make_resilient_lane(policy: StealPolicy, ops, worker_fn, *,
 
         # Normal rebalancing over the survivors.
         sizes = master_ops.gather_sizes(q, worker_axis=axis_name)
-        plan = masked_plan(sizes, dead, pol)
-        plan = jnp.where(drop, _noop_plan(sizes.shape[0]), plan)
-        if stage == "exchange":
-            token = master_ops.exchange_probe(q, pol, axis_name=axis_name,
-                                              ops=ops, plan=plan)
-            return q, carry, token
+        with jax.named_scope("master.plan"):
+            plan = masked_plan(sizes, dead, pol)
+            plan = jnp.where(drop, _noop_plan(sizes.shape[0]), plan)
         q, stats = master_ops.superstep(q, pol, axis_name=axis_name,
                                         ops=ops, plan=plan)
 
         # Recovery: dead rings stolen at proportion 1.0 by the least-
         # loaded survivors, through the identical exchange.
         sizes2 = master_ops.gather_sizes(q, worker_axis=axis_name)
-        rplan = recovery_plan(sizes2, dead, max_steal=pol.max_steal,
-                              capacity=cap)
-        rplan = jnp.where(drop, _noop_plan(sizes2.shape[0]), rplan)
+        with jax.named_scope("master.plan"):
+            rplan = recovery_plan(sizes2, dead, max_steal=pol.max_steal,
+                                  capacity=cap)
+            rplan = jnp.where(drop, _noop_plan(sizes2.shape[0]), rplan)
         q, rstats = master_ops.superstep(q, pol, axis_name=axis_name,
                                          ops=ops, plan=rplan)
 
@@ -453,8 +442,6 @@ def make_resilient_lane(policy: StealPolicy, ops, worker_fn, *,
             skip = i_am_dead | i_am_delayed
             q = _select(skip, q, q_new)
             carry = _select(skip, carry, carry_new)
-        if stage == "worker":
-            return q, carry, master_ops.probe_token(q)
 
         pol = dataclasses.replace(policy, proportion=proportion)
         cap = jax.tree_util.tree_leaves(q.buf)[0].shape[0]
@@ -462,12 +449,9 @@ def make_resilient_lane(policy: StealPolicy, ops, worker_fn, *,
 
         # (1) Intra-pod normal superstep, the pod's dead lanes masked.
         sizes_pod = master_ops.gather_sizes(q, worker_axis=axis_name)
-        plan = masked_plan(sizes_pod, dead_intra, pol)
-        plan = jnp.where(drop, _noop_plan(pod_size), plan)
-        if stage == "exchange":
-            token = master_ops.exchange_probe(q, pol, axis_name=axis_name,
-                                              ops=ops, plan=plan)
-            return q, carry, token
+        with jax.named_scope("master.plan"):
+            plan = masked_plan(sizes_pod, dead_intra, pol)
+            plan = jnp.where(drop, _noop_plan(pod_size), plan)
         q, intra = master_ops.superstep(q, pol, axis_name=axis_name,
                                         ops=ops, plan=plan)
 
@@ -479,8 +463,9 @@ def make_resilient_lane(policy: StealPolicy, ops, worker_fn, *,
         eff_size = jnp.where((w_idx == 0) & ~rep_dead, q.size, sentinel)
         q_eff = QueueState(buf=q.buf, lo=q.lo, size=eff_size)
         sizes_x = lax.all_gather(eff_size, pod_axis)   # (n_pods,) per row
-        pod_plan = plan_transfers(sizes_x, pol)
-        pod_plan = jnp.where(drop, _noop_plan(n_pods), pod_plan)
+        with jax.named_scope("master.plan"):
+            pod_plan = plan_transfers(sizes_x, pol)
+            pod_plan = jnp.where(drop, _noop_plan(n_pods), pod_plan)
         q_eff, pod_stats = master_ops.superstep(q_eff, pol,
                                                 axis_name=pod_axis,
                                                 ops=ops, plan=pod_plan)
@@ -491,9 +476,10 @@ def make_resilient_lane(policy: StealPolicy, ops, worker_fn, *,
         # pod-mates (dead-fullest -> alive-emptiest, proportion 1.0).
         # No-op in an entirely dead pod — no live thief exists there.
         sizes2 = master_ops.gather_sizes(q, worker_axis=axis_name)
-        rplan = recovery_plan(sizes2, dead_intra, max_steal=pol.max_steal,
-                              capacity=cap)
-        rplan = jnp.where(drop, _noop_plan(pod_size), rplan)
+        with jax.named_scope("master.plan"):
+            rplan = recovery_plan(sizes2, dead_intra,
+                                  max_steal=pol.max_steal, capacity=cap)
+            rplan = jnp.where(drop, _noop_plan(pod_size), rplan)
         q, irec = master_ops.superstep(q, pol, axis_name=axis_name,
                                        ops=ops, plan=rplan)
 
@@ -502,9 +488,11 @@ def make_resilient_lane(policy: StealPolicy, ops, worker_fn, *,
         # pod's lane-w, riding the same exchange over the pod axis.
         dead_row = dead2d[:, w_idx]                    # (n_pods,) my row
         sizes_row = lax.all_gather(q.size, pod_axis)
-        xplan = recovery_plan(sizes_row, pod_dead, max_steal=pol.max_steal,
-                              capacity=cap, thief_ok=~dead_row)
-        xplan = jnp.where(drop, _noop_plan(n_pods), xplan)
+        with jax.named_scope("master.plan"):
+            xplan = recovery_plan(sizes_row, pod_dead,
+                                  max_steal=pol.max_steal, capacity=cap,
+                                  thief_ok=~dead_row)
+            xplan = jnp.where(drop, _noop_plan(n_pods), xplan)
         q, xrec = master_ops.superstep(q, pol, axis_name=pod_axis,
                                        ops=ops, plan=xplan)
 

@@ -5,10 +5,7 @@ never touches a device): each rebalancing round appends one
 :class:`RoundRecord` with the steal count, items/bytes moved, the
 exchange payload (``bytes_moved`` — what the round's block collective
 carried per lane, the Fig. 10 scaling metric), the queue-depth histogram
-and imbalance statistics — plus, when the phase probe is armed
-(``StealRuntime.attach_phase_probe``, DESIGN.md §11), the round's
-wall-clock split across ``worker_body`` / ``exchange`` / ``splice`` /
-``adaptive_update``.  Wave-level consumers (the serving engine) append
+and imbalance statistics.  Wave-level consumers (the serving engine) append
 :class:`WaveRecord` entries through the same object, and fault /
 detector transitions land both as counters (:attr:`Telemetry.
 fault_events`) and as a round-stamped event log (:attr:`Telemetry.
@@ -31,8 +28,9 @@ exactly what :mod:`repro.obs.trace` renders and
 * ``straggler_steps`` always, ``faults`` (the event counters dict) when
   any were recorded.
 
-Per-phase wall-clock aggregates live in :meth:`Telemetry.phase_summary`,
-kept separate because they exist only on probed runs.
+How a round's wall-clock splits is not recorded here: the host side
+comes from :mod:`repro.obs.spans`, the device side from the named
+scopes in the profiler's trace (DESIGN.md §11).
 """
 
 from __future__ import annotations
@@ -91,14 +89,7 @@ def reduce_round_stats(stats, *, n_workers: int, pod_size: Optional[int] = None
 
 @dataclasses.dataclass(frozen=True)
 class RoundRecord:
-    """One rebalancing round, as observed by the master.
-
-    The ``t_*`` phase fields are zero unless the round ran under an
-    armed phase probe (``StealRuntime.attach_phase_probe``) — then they
-    attribute the round's wall-clock in seconds, ``phase_timed`` is
-    True, and ``phase_estimated`` distinguishes a fused block's
-    calibrated split from the unfused path's fence-bounded measurement
-    (:mod:`repro.obs.phase`)."""
+    """One rebalancing round, as observed by the master."""
 
     round: int
     proportion: float          # steal proportion used THIS round
@@ -110,13 +101,6 @@ class RoundRecord:
     sizes_max: int
     sizes_mean: float
     depth_hist: Sequence[int]  # queue-depth histogram over workers
-    t_worker: float = 0.0      # wall seconds: worker body
-    t_exchange: float = 0.0    # wall seconds: block-exchange collective
-    t_splice: float = 0.0      # wall seconds: splice + bookkeeping tail
-    t_adaptive: float = 0.0    # wall seconds: adaptive proportion update
-    t_round: float = 0.0       # wall seconds attributed to this round
-    phase_timed: bool = False
-    phase_estimated: bool = False
 
     @property
     def imbalance(self) -> float:
@@ -204,25 +188,11 @@ class Telemetry:
         self.straggler_steps = 0
 
     def record(self, *, sizes, n_steals: int, n_transferred: int,
-               proportion: float, bytes_moved: int = 0,
-               phases: Optional[Dict[str, Any]] = None) -> RoundRecord:
-        """Append one round.  ``phases`` optionally carries the phase
-        probe's wall-clock attribution — the dict
-        :meth:`repro.obs.phase.PhaseSample.as_record` produces
-        (``t_worker``/``t_exchange``/``t_splice``/``t_adaptive``/
-        ``t_round``/``phase_estimated``); kept a plain mapping so this
-        module stays numpy-only."""
+               proportion: float, bytes_moved: int = 0) -> RoundRecord:
+        """Append one round."""
         sizes = np.asarray(sizes)
         hi = self.capacity if self.capacity else max(int(sizes.max()), 1)
         hist, _ = np.histogram(sizes, bins=self.n_bins, range=(0, hi))
-        extra: Dict[str, Any] = {}
-        if phases is not None:
-            extra = {k: phases.get(k, 0.0)
-                     for k in ("t_worker", "t_exchange", "t_splice",
-                               "t_adaptive", "t_round")}
-            extra["phase_estimated"] = bool(
-                phases.get("phase_estimated", False))
-            extra["phase_timed"] = True
         rec = RoundRecord(
             round=len(self.rounds),
             proportion=float(proportion),
@@ -234,7 +204,6 @@ class Telemetry:
             sizes_max=int(sizes.max()) if sizes.size else 0,
             sizes_mean=float(sizes.mean()) if sizes.size else 0.0,
             depth_hist=tuple(int(x) for x in hist),
-            **extra,
         )
         self.rounds.append(rec)
         return rec
@@ -316,34 +285,6 @@ class Telemetry:
     @property
     def total_tokens(self) -> int:
         return sum(w.tokens for w in self.waves)
-
-    def phase_summary(self) -> Dict[str, Any]:
-        """Aggregate the probed rounds' wall-clock attribution: per phase
-        (``worker_body`` / ``exchange`` / ``splice`` /
-        ``adaptive_update``) the total and mean seconds plus the fraction
-        of attributed wall, and the timed/estimated round counts.  Rounds
-        recorded without a probe are excluded; with none probed the dict
-        is just ``{"timed_rounds": 0}``."""
-        timed = [r for r in self.rounds if r.phase_timed]
-        out: Dict[str, Any] = {"timed_rounds": len(timed)}
-        if not timed:
-            return out
-        out["estimated_rounds"] = sum(1 for r in timed if r.phase_estimated)
-        totals = {
-            "worker_body": sum(r.t_worker for r in timed),
-            "exchange": sum(r.t_exchange for r in timed),
-            "splice": sum(r.t_splice for r in timed),
-            "adaptive_update": sum(r.t_adaptive for r in timed),
-        }
-        wall = sum(r.t_round for r in timed)
-        out["wall_s"] = wall
-        denom = sum(totals.values()) or 1.0
-        out["phases"] = {
-            name: {"total_s": t, "mean_s": t / len(timed),
-                   "fraction": t / denom}
-            for name, t in totals.items()
-        }
-        return out
 
     def summary(self) -> Dict[str, Any]:
         props = [r.proportion for r in self.rounds]

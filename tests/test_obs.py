@@ -1,8 +1,7 @@
-"""Observability package coverage (ISSUE 10): phase-probe contracts
-(bit-identity, compile-identity when off), trace export + validation,
-metrics registry / exposition / collectors, the resilient-run textfile,
-wall-clock failure detection, PagedQueue spill accounting, and the
-perf-trend gate.
+"""Observability package coverage: zero recompiles on a repeated drive,
+trace export + validation, metrics registry / exposition / collectors,
+the resilient-run textfile, wall-clock failure detection, PagedQueue
+spill accounting, and the perf-trend gate.
 """
 
 import json
@@ -42,69 +41,32 @@ def _body(ops):
     return body
 
 
-def _drive(rt, *, rounds=5, fused=2):
+def _drive(rt, *, rounds=5, fused=2, body=None):
     carry = jnp.zeros((rt.n_workers,), jnp.int32)
-    body = _body(rt.ops)
+    body = body or _body(rt.ops)
     for _ in range(rounds):
         carry, _ = rt.round(body, carry)
     carry, _ = rt.run_fused(fused, body, carry)
     return carry
 
 
-def _state(rt, carry):
-    return [np.asarray(x)
-            for x in jax.tree_util.tree_leaves((rt.queues, carry))]
+# -- compiled programs -------------------------------------------------------
 
 
-# -- phase probe contracts ---------------------------------------------------
-
-
-def test_probed_run_bit_identical_to_unprobed():
-    ref = _make_rt()
-    _seed(ref)
-    ref_carry = _drive(ref)
-
-    probed = _make_rt()
-    _seed(probed)
-    probed.attach_phase_probe(calibrate_every=4)
-    probed_carry = _drive(probed)
-
-    for a, b in zip(_state(ref, ref_carry), _state(probed, probed_carry)):
-        np.testing.assert_array_equal(a, b)
-    assert ref.telemetry.summary() == probed.telemetry.summary()
-    ps = probed.telemetry.phase_summary()
-    assert ps["timed_rounds"] == len(probed.telemetry.rounds)
-    assert ps["estimated_rounds"] == 2        # the fused block's rounds
-    assert ps["wall_s"] > 0.0
-    # Phases partition the attributed wall.
-    fr = sum(p["fraction"] for p in ps["phases"].values())
-    assert fr == pytest.approx(1.0)
-
-
-def test_disabled_probe_compiles_nothing_extra():
-    ref = _make_rt()
-    _seed(ref)
-    ref_carry = _drive(ref)
-
-    off = _make_rt()
-    _seed(off)
-    off.attach_phase_probe().enabled = False
-    off_carry = _drive(off)
-
-    assert compile_count(off) == compile_count(ref)
-    assert len(off._probe_compiled) == 0
-    for a, b in zip(_state(ref, ref_carry), _state(off, off_carry)):
-        np.testing.assert_array_equal(a, b)
-    assert off.telemetry.phase_summary() == {"timed_rounds": 0}
-
-
-def test_estimated_sample_counts_all_fused_rounds():
-    rt = _make_rt()
+def test_second_drive_compiles_nothing():
+    """The same worker body driven again through ``round`` and
+    ``run_fused`` reuses the programs the first drive compiled, with the
+    fault layer and the detector armed."""
+    rt = _make_rt(fault_plan=FaultPlan())
+    rt.attach_detector(DetectorPolicy(suspect_after=2))
+    body = _body(rt.ops)
     _seed(rt)
-    probe = rt.attach_phase_probe(calibrate_every=1000)
-    _drive(rt, rounds=2, fused=3)
-    assert probe.rounds_attributed == 5  # 2 direct + 3 estimated
-    assert probe.calibrations == 1       # the first fused block
+    _drive(rt, body=body)
+    programs, compiles = set(rt._compiled), compile_count(rt)
+    _seed(rt)
+    _drive(rt, body=body)
+    assert set(rt._compiled) == programs
+    assert compile_count(rt) == compiles
 
 
 # -- trace export ------------------------------------------------------------
@@ -114,7 +76,6 @@ def _traced_telemetry():
     rt = _make_rt(fault_plan=FaultPlan(kills=((3, 4),)))
     rt.attach_detector(DetectorPolicy(suspect_after=2, dead_after=None))
     _seed(rt)
-    rt.attach_phase_probe(calibrate_every=4)
     carry = jnp.zeros((rt.n_workers,), jnp.int32)
     body = _body(rt.ops)
     for tick in range(5):
@@ -137,7 +98,6 @@ def test_trace_export_loads_and_validates(tmp_path):
     assert counts["wave"] == len(tele.waves)
     assert counts["request"] == 3 * len(tele.requests)  # b/n/e per request
     assert counts["fault"] == len(tele.fault_log) >= 1  # the planned kill
-    assert counts["phase"] > 0
     assert validate_trace(trace) == counts
 
 
@@ -175,15 +135,13 @@ def test_registry_exposition_and_snapshot():
         reg.gauge("t_total", "type clash")
 
 
-def test_runtime_metrics_cover_rounds_phases_and_detector():
+def test_runtime_metrics_cover_rounds_and_detector():
     rt = _make_rt(fault_plan=FaultPlan())
     rt.attach_detector(DetectorPolicy(suspect_after=2))
     _seed(rt)
-    rt.attach_phase_probe()
     _drive(rt, rounds=3, fused=2)
     snap = rt.metrics().snapshot()
     assert snap["repro_rounds_total"]["values"] == 5
-    assert "repro_phase_seconds_total" in snap
     healthy = snap["repro_detector_lanes"]["values"]['{state="healthy"}']
     assert healthy == rt.n_workers
     assert snap["repro_queue_items"]["values"] == int(rt.sizes().sum())

@@ -22,7 +22,7 @@ from repro.core.dd.parallel import parallel_solve
 from repro.core.policy import StealPolicy
 from repro.distributed.elastic import compile_count
 from repro.obs import spans
-from repro.runtime import StealRuntime
+from repro.runtime import FaultPlan, StealRuntime
 from repro.runtime.executor import LiftedJit
 
 N_NODES = 600
@@ -291,12 +291,12 @@ _SCOPE_CHECK = textwrap.dedent("""
     from repro.core.policy import StealPolicy
     from repro.distributed import launch_runtime
 
-    def op_names(execution, body_of):
+    def op_names(execution, body_of, **lane):
         rt = launch_runtime(4, 256, jax.ShapeDtypeStruct((), jnp.int32),
                             execution=execution, max_pop=8,
                             policy=StealPolicy(queue_limit=2, low_watermark=1,
                                                high_watermark=8,
-                                               max_steal=64))
+                                               max_steal=64), **lane)
         lowered = rt.lower(body_of(rt.ops), jnp.zeros((4,), jnp.int32),
                            fused=8)
         text = lowered.compile().as_text()
@@ -309,16 +309,23 @@ _SCOPE_CHECK = textwrap.dedent("""
 """)
 
 
-def _found(execution):
+def _found(execution, **lane):
     ns = {}
     exec(compile(_SCOPE_CHECK, "<scope-check>", "exec"), ns)
-    return ns["scopes_found"](ns["op_names"](execution, _body), SCOPES)
+    return ns["scopes_found"](ns["op_names"](execution, _body, **lane),
+                              SCOPES)
 
 
-def test_named_scopes_reach_the_compiled_round_vmap():
-    # Under vmap the size all_gather is the stacked sizes themselves: it
-    # lowers to no instruction, so master.sizes has nothing to name.
-    assert _found("vmap") == set(SCOPES) - {"master.sizes"}
+# Under vmap a flat size all_gather is the stacked sizes themselves: it
+# lowers to no instruction, so master.sizes has nothing to name there.
+# The pod grid's gathers over both axes do lower, and keep the name.
+@pytest.mark.parametrize("lane, absent", [
+    ({}, {"master.sizes"}),
+    ({"fault_plan": FaultPlan()}, {"master.sizes"}),
+    ({"pod_size": 2, "fault_plan": FaultPlan()}, set()),
+], ids=["plain", "resilient", "resilient-pods"])
+def test_named_scopes_reach_the_compiled_round_vmap(lane, absent):
+    assert _found("vmap", **lane) == set(SCOPES) - absent
 
 
 def test_named_scopes_reach_the_compiled_round_mesh():

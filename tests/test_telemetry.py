@@ -3,7 +3,6 @@ direct ``reduce_round_stats`` unit coverage (flat and hierarchical
 xpod accounting), and degenerate percentile inputs."""
 
 import numpy as np
-import pytest
 
 from repro.runtime.telemetry import Telemetry, reduce_round_stats
 
@@ -34,7 +33,6 @@ def test_summary_on_empty_stream():
     assert s["imbalance_final"] == 0.0
     assert s["straggler_steps"] == 0
     assert "waves" not in s and "requests" not in s and "faults" not in s
-    assert tele.phase_summary() == {"timed_rounds": 0}
 
 
 def test_reduce_round_stats_flat_reads_replicated_element():
@@ -96,16 +94,3 @@ def test_wave_round_alignment_and_fault_log_stamps():
     tele.record_fault("restart")             # not lane-attributed
     assert tele.fault_log[-1] == ("restart", -1, 1)
     assert tele.summary()["faults"] == {"kill": 1, "restart": 1}
-
-
-def test_record_phases_roundtrip():
-    tele = Telemetry()
-    tele.record(sizes=np.asarray([2, 2]), n_steals=0, n_transferred=0,
-                proportion=0.5,
-                phases={"t_worker": 0.6, "t_exchange": 0.2,
-                        "t_splice": 0.1, "t_adaptive": 0.1,
-                        "t_round": 1.0, "phase_estimated": True})
-    ps = tele.phase_summary()
-    assert ps["timed_rounds"] == 1 and ps["estimated_rounds"] == 1
-    assert ps["wall_s"] == pytest.approx(1.0)
-    assert ps["phases"]["worker_body"]["fraction"] == pytest.approx(0.6)
