@@ -135,6 +135,9 @@ class MeshStealRuntime(StealRuntime):
 
     # -- the round, shard_mapped --------------------------------------------
 
+    def _replicated_sharding(self):
+        return NamedSharding(self.mesh, P())
+
     def _axes_tuple(self) -> tuple:
         return tuple(self.mesh.axis_names)
 
@@ -220,7 +223,9 @@ class MeshStealRuntime(StealRuntime):
         stacked per shard, all-gathered once at the block edge into the
         vmapped runtime's ``(k, W, ...)`` layout and packed with the
         round count and the final proportion into one replicated buffer,
-        so the host reads one shard once."""
+        so the host reads one shard once.  The final proportion and the
+        advanced fault context come back replicated too, placed as the
+        next dispatch takes them."""
         one_round = self._fused_round(worker_fn)
         lane, entry = self._lane_spec, self._lane_entry
         axes = self._axes_tuple()
@@ -253,8 +258,8 @@ class MeshStealRuntime(StealRuntime):
                     q, c, p, ctx, tele, _total = one_round(q, c, p, ctx)
                     return (q, c, p, ctx), tele
 
-                (q, c, p, _ctx), tele = lax.scan(body, (q, c, p0, ctx0),
-                                                 None, length=k)
+                (q, c, p, ctx), tele = lax.scan(body, (q, c, p0, ctx0),
+                                                None, length=k)
                 rounds = jnp.int32(k)
             else:
                 tele0 = self._tele_slots(k)
@@ -272,22 +277,23 @@ class MeshStealRuntime(StealRuntime):
                     return (q, c, p, ctx, r + 1, tele, total)
 
                 total0 = lax.psum(q.size, axes)  # replicated global size
-                q, c, p, _ctx, rounds, tele, _ = lax.while_loop(
+                q, c, p, ctx, rounds, tele, _ = lax.while_loop(
                     cond, body,
                     (q, c, p0, ctx0, jnp.int32(0), tele0, total0))
 
             tele = dict(gather_lanes({"stats": tele["stats"],
                                       "sizes": tele["sizes"]}),
                         proportion=tele["proportion"])
-            return (_add_lane(q), _add_lane(c), p,
+            return (_add_lane(q), _add_lane(c), p, ctx,
                     layout.pack(rounds, p, tele))
 
         fused = jax.shard_map(
             local_fused, mesh=self.mesh,
             in_specs=(lane, lane, P(), ctx_spec),
-            out_specs=(lane, lane, P(), P()),
+            out_specs=(lane, lane, P(), ctx_spec, P()),
             check_vma=False)
-        return FusedProgram(fused, layout, self._donate_argnums())
+        return FusedProgram(fused, layout, self._donate_argnums(),
+                            self._replicated_sharding())
 
     # -- resilience: elastic state shardings ---------------------------------
 

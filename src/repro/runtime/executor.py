@@ -75,10 +75,18 @@ class LiftedJit:
     compiled program as arguments instead of being embedded in it as
     constants.  A program that embeds gigabytes of weights cannot be
     compiled.  Each new signature of the arguments is traced once to a
-    jaxpr, whose constants are then passed on every call."""
+    jaxpr, whose constants are then passed on every call.
 
-    def __init__(self, fn: Callable, donate_argnums: tuple = ()):
+    The constants are placed once, when their signature is traced:
+    committed with ``const_sharding`` where one is given (the replicated
+    sharding a mesh program reads them with), on the default device
+    otherwise.  A call then hands them to the program as they are, with
+    no copy."""
+
+    def __init__(self, fn: Callable, donate_argnums: tuple = (),
+                 const_sharding=None):
         self._fn = fn
+        self._const_sharding = const_sharding
         self._traced: Dict[Any, Tuple[Any, Any]] = {}
         self._jit = jax.jit(self._eval, static_argnums=(0,),
                             donate_argnums=tuple(i + 2
@@ -102,7 +110,8 @@ class LiftedJit:
                 *args)
             self._traced[key] = ((closed.jaxpr,
                                   jax.tree_util.tree_structure(shape)),
-                                 closed.consts)
+                                 jax.device_put(closed.consts,
+                                                self._const_sharding))
         return key
 
     def __call__(self, *args):
@@ -169,12 +178,14 @@ class ReadbackLayout:
 
 
 class FusedProgram(LiftedJit):
-    """A fused block ``(qs, carry, p0, ctx0) -> (qs, carry, p, packed)``
-    and the :class:`ReadbackLayout` its ``packed`` output follows."""
+    """A fused block ``(qs, carry, p0, ctx0) -> (qs, carry, p, ctx,
+    packed)`` and the :class:`ReadbackLayout` its ``packed`` output
+    follows.  ``p`` and ``ctx`` are the proportion and the fault context
+    the next round would start from, left on the devices."""
 
     def __init__(self, fn: Callable, layout: ReadbackLayout,
-                 donate_argnums: tuple = ()):
-        super().__init__(fn, donate_argnums)
+                 donate_argnums: tuple = (), const_sharding=None):
+        super().__init__(fn, donate_argnums, const_sharding)
         self.layout = layout
 
 
@@ -330,6 +341,11 @@ class StealRuntime:
         self.telemetry = Telemetry(item_bytes=item_nbytes(item_spec),
                                    capacity=capacity)
         self.rounds_run = 0
+        # The proportion and the round index the last fused dispatch left
+        # on the devices, each beside the host value it stands for (the
+        # proportion's float32 bits, the round): see _fused_scalars.
+        self._held_p: Tuple[Any, Any] = (None, None)
+        self._held_ctx: Tuple[Any, Any] = (None, None)
         self._compiled: Dict[Any, Callable] = (
             {} if programs is None else programs)
         # Resilience: the host-side fault schedule (None = machinery off,
@@ -665,6 +681,33 @@ class StealRuntime:
             return self.fault.ctx(round0)
         return jnp.int32(round0)
 
+    def _fused_scalars(self, round0: int):
+        """The proportion and the fault context of a fused dispatch that
+        starts at global round ``round0``.
+
+        The previous dispatch left both on the devices.  They are handed
+        on as they are while the host still holds the values they stand
+        for, so a steady run of dispatches puts nothing.  A value the host
+        changed (a straggler boost, a restore, rounds run by
+        :meth:`round`) is put afresh, placed where the block leaves its
+        own, so both kinds of call run one executable.  An armed fault
+        layer's context is the host's schedule and is put every time.
+        ``steal.enqueue_puts`` counts the puts."""
+        sharding = self._replicated_sharding()
+        value = np.float32(self.proportion)
+        bits, p = self._held_p
+        if bits != value.view(np.uint32):
+            p = jax.device_put(value, sharding)
+            spans.count("steal.enqueue_puts")
+        held_round, ctx = self._held_ctx
+        if self.fault is not None:
+            ctx = self._ctx(round0)
+            spans.count("steal.enqueue_puts")
+        elif held_round != round0:
+            ctx = jax.device_put(np.int32(round0), sharding)
+            spans.count("steal.enqueue_puts")
+        return p, ctx
+
     def _make_step(self, worker_fn: Optional[WorkerFn]) -> Callable:
         """Un-jitted ``(qs, carry, proportion, ctx) -> (qs, carry, stats)``."""
         pod_size = self.pod_size
@@ -700,8 +743,17 @@ class StealRuntime:
     def _donate_argnums() -> tuple:
         return () if jax.default_backend() == "cpu" else (0,)
 
+    def _replicated_sharding(self):
+        """Where a value that every lane reads whole is placed: a worker
+        body's closed-over constants, the proportion and the round index.
+        ``None`` here, JAX's default device uncommitted, which is where
+        the vmapped blocks leave their own outputs; the mesh runtime
+        replicates them over its devices."""
+        return None
+
     def _compile(self, worker_fn: Optional[WorkerFn]) -> Callable:
-        return LiftedJit(self._make_step(worker_fn), self._donate_argnums())
+        return LiftedJit(self._make_step(worker_fn), self._donate_argnums(),
+                         self._replicated_sharding())
 
     def _compile_fused(self, worker_fn: Optional[WorkerFn], k: int,
                        until_drained: bool = False) -> Callable:
@@ -714,7 +766,8 @@ class StealRuntime:
         preallocated ``(k, ...)`` slots and returning the executed round
         count.  The round count, the final proportion and the telemetry
         leave the device packed in one int32 buffer
-        (:class:`ReadbackLayout`)."""
+        (:class:`ReadbackLayout`); the final proportion and the advanced
+        fault context also stay on the device, for the next dispatch."""
         step = self._make_step(worker_fn)
         policy, controller = self.policy, self.controller
         config = controller.config if controller else None
@@ -738,11 +791,12 @@ class StealRuntime:
                     qs, carry, p, ctx, tele = one_round(qs, carry, p, ctx)
                     return (qs, carry, p, ctx), tele
 
-                (qs, carry, p, _ctx), tele = lax.scan(
+                (qs, carry, p, ctx), tele = lax.scan(
                     body, (qs, carry, p0, ctx0), None, length=k)
-                return qs, carry, p, layout.pack(jnp.int32(k), p, tele)
+                return qs, carry, p, ctx, layout.pack(jnp.int32(k), p, tele)
 
-            return FusedProgram(fused, layout, self._donate_argnums())
+            return FusedProgram(fused, layout, self._donate_argnums(),
+                                self._replicated_sharding())
 
         def fused(qs, carry, p0, ctx0):
             tele_sds = jax.eval_shape(
@@ -763,11 +817,12 @@ class StealRuntime:
                         buf, v, r, 0), tele, t)
                 return (qs, carry, p, ctx, r + 1, tele)
 
-            qs, carry, p, _ctx, r, tele = lax.while_loop(
+            qs, carry, p, ctx, r, tele = lax.while_loop(
                 cond, body, (qs, carry, p0, ctx0, jnp.int32(0), tele0))
-            return qs, carry, p, layout.pack(r, p, tele)
+            return qs, carry, p, ctx, layout.pack(r, p, tele)
 
-        return FusedProgram(fused, layout, self._donate_argnums())
+        return FusedProgram(fused, layout, self._donate_argnums(),
+                            self._replicated_sharding())
 
     # -- observability -------------------------------------------------------
 
@@ -882,7 +937,10 @@ class StealRuntime:
         float32 computation the host controller runs, so the trajectory
         is identical), and per-round telemetry is stacked ``(k, ...)``
         and read back, with the round count and the final proportion, in
-        one device-to-host transfer at the end.
+        one device-to-host transfer at the end.  The final proportion and
+        the round index also stay on the devices and start the next
+        dispatch, so a steady run puts nothing on enqueue
+        (:meth:`_fused_scalars`).
 
         With ``until_drained=False`` (default) the block is a
         ``lax.scan`` of exactly ``k`` rounds, returning
@@ -910,10 +968,10 @@ class StealRuntime:
                 if carry is None:
                     carry = jnp.zeros((self.n_workers,), jnp.int32)
                 snap = self._pre_dispatch_snapshot(worker_fn)
-                p0 = jnp.float32(self.proportion)
-                args = (self.queues, carry, p0, self._ctx(self.rounds_run))
+                round0 = self.rounds_run
+                args = (self.queues, carry, *self._fused_scalars(round0))
                 t0 = time.perf_counter()
-                self.queues, carry, _p_final, packed = fn(*args)
+                self.queues, carry, p_dev, ctx_dev, packed = fn(*args)
             with spans.span("steal:wait"):
                 # The dispatch's one device-to-host read: it waits for the
                 # block and brings every value the host needs.
@@ -927,6 +985,8 @@ class StealRuntime:
                 spans.count("steal.rounds", rounds)
                 stats = self._record_fused(tele, rounds, p_final, wall_s,
                                            snap)
+                self._held_p = (np.float32(p_final).view(np.uint32), p_dev)
+                self._held_ctx = (round0 + rounds, ctx_dev)
                 if until_drained:
                     stats = jax.tree_util.tree_map(lambda x: x[:rounds],
                                                    stats)

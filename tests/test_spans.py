@@ -5,6 +5,7 @@ scopes of the round reach the compiled program's metadata."""
 
 import contextlib
 import glob
+import json
 import os
 import subprocess
 import sys
@@ -143,6 +144,64 @@ def test_one_host_read_per_fused_dispatch(mode, tmp_path):
     counters = spans.snapshot()["counters"]
     assert counters["steal.host_reads"] == counters["steal.dispatches"]
     assert counters["steal.host_reads"] == dispatches
+
+
+def _puts_per_dispatch(execution, mode, path):
+    """``steal.enqueue_puts`` added by each fused dispatch of a drain."""
+    from repro.distributed import launch_runtime
+
+    rt = launch_runtime(4, 256, jax.ShapeDtypeStruct((), jnp.int32),
+                        execution=execution, max_pop=8,
+                        policy=StealPolicy(queue_limit=2, low_watermark=1,
+                                           high_watermark=8, max_steal=64))
+    rt.push(0, jnp.zeros((1,), jnp.int32), 1)
+    body = _body(rt.ops)
+    carry = jnp.zeros((4,), jnp.int32)
+    puts = []
+    with recording(path):
+        while rt.total_size() > 0:
+            before = spans.snapshot()["counters"].get("steal.enqueue_puts", 0)
+            carry = rt.run_fused(4, body, carry,
+                                 until_drained=mode == "drained")[0]
+            puts.append(spans.snapshot()["counters"]["steal.enqueue_puts"]
+                        - before)
+    return puts
+
+
+@pytest.fixture(scope="module")
+def mesh_puts():
+    """``_puts_per_dispatch`` on ``MeshStealRuntime`` over four CPU
+    devices, in a subprocess that sets them up, for each mode."""
+    script = ('import json, sys, tempfile\n'
+              f'sys.path.insert(0, {os.path.dirname(__file__)!r})\n'
+              'from test_spans import _puts_per_dispatch\n'
+              'out = {m: _puts_per_dispatch("mesh", m, tempfile.mkdtemp())\n'
+              '       for m in ("drained", "scan")}\n'
+              'print("PUTS " + json.dumps(out))\n')
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join(
+                   [src] + os.environ.get("PYTHONPATH", "").split(os.pathsep)))
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=600)
+    line = [ln for ln in out.stdout.splitlines() if ln.startswith("PUTS ")]
+    assert line, out.stderr[-2000:]
+    return json.loads(line[0][len("PUTS "):])
+
+
+@pytest.mark.parametrize("execution", ["vmap", "mesh"])
+@pytest.mark.parametrize("mode", ["drained", "scan"])
+def test_enqueue_puts_two_then_none(execution, mode, tmp_path, request):
+    """A fresh runtime's first fused dispatch puts the proportion and the
+    round index; every later dispatch of a steady drain starts from the
+    values the one before left on the devices."""
+    if execution == "vmap" or jax.device_count() >= 4:
+        puts = _puts_per_dispatch(execution, mode, tmp_path)
+    else:
+        puts = request.getfixturevalue("mesh_puts")[mode]
+    assert len(puts) > 2
+    assert puts == [2] + [0] * (len(puts) - 1)
 
 
 @pytest.mark.parametrize("mode", ["drained", "scan", "round"])
