@@ -17,6 +17,9 @@ Layers:
   dd            decision-diagram branch-and-bound solver (paper's application)
   uts           UTS binomial trees (SHA-1 spawning on the device) searched
                 on the steal runtime, with a hashlib reference walk
+  irregular     the Fig. 9 DAG with heavy-tailed per-node cost (SHA-1
+                units): a worker body that keeps nodes in flight across
+                rounds, with a hashlib reference walk
 
 (The pre-BulkOps ``use_kernel`` dialect — module-level queue ops and
 their ``*_inplace`` variants — had its one deprecation release at PR 3

@@ -23,7 +23,8 @@ hierarchical supersteps).  Each device owns exactly one queue lane:
   ``lax.scan`` (or the ``until_drained`` ``lax.while_loop``) INSIDE the
   shard_map block: k rounds of collectives + adaptive feedback run
   without the host in the loop, and the drain check is a replicated
-  cross-shard size reduction (every device takes the same exit branch).
+  cross-shard reduction of the queue sizes and of any work in flight the
+  carry reports (every device takes the same exit branch).
 * **Exact cross-host telemetry** — each shard stacks its OWN lane's
   per-round ``RebalanceStats`` counters; one all-gather of them all at
   the block edge puts them back into the vmapped runtime's exact ``(k, W, ...)``
@@ -54,7 +55,8 @@ from repro.core import ops as bulk_ops
 from repro.runtime import resilience
 from repro.runtime.adaptive import adaptive_update
 from repro.runtime.executor import (FusedProgram, ReadbackLayout,
-                                    StealRuntime, WorkerFn, make_lane_step)
+                                    StealRuntime, WorkerFn, in_flight,
+                                    make_lane_step)
 
 __all__ = ["MeshStealRuntime"]
 
@@ -166,7 +168,8 @@ class MeshStealRuntime(StealRuntime):
     def _fused_round(self, worker_fn: Optional[WorkerFn]) -> Callable:
         """Per-shard ``(q, carry, p) -> (q, carry, p', tele, total)``:
         one round plus the on-device adaptive update and the replicated
-        global size total (the drain signal).  ``tele`` leaves carry a
+        global total of queued items and of the work in flight the carry
+        reports (the drain signal).  ``tele`` leaves carry a
         leading local-lane dim so shard_map's out specs can gather them
         into the vmapped runtime's exact telemetry layout."""
         lane_fn = self._lane_step(worker_fn)
@@ -174,6 +177,7 @@ class MeshStealRuntime(StealRuntime):
         config = controller.config if controller else None
         worker_axis = self.axis_name
         pod_axis = self.pod_axis if self.pod_size is not None else None
+        axes = self._axes_tuple()
 
         def one_round(q, carry, p, ctx):
             q, carry, stats = lane_fn(q, carry, p, ctx)
@@ -193,7 +197,11 @@ class MeshStealRuntime(StealRuntime):
                 masked = resilience.mask_sizes(sizes_vec, ctx, policy)
                 p = adaptive_update(p, masked, policy=policy,
                                     config=config)
-            return q, carry, p, ctx, tele, jnp.sum(sizes_vec)
+            total = jnp.sum(sizes_vec)
+            held = in_flight(carry)
+            if held is not None:
+                total = total + lax.psum(held, axes)
+            return q, carry, p, ctx, tele, total
 
         return one_round
 
@@ -276,7 +284,11 @@ class MeshStealRuntime(StealRuntime):
                             buf, v, r, 0), tele, t)
                     return (q, c, p, ctx, r + 1, tele, total)
 
-                total0 = lax.psum(q.size, axes)  # replicated global size
+                # The replicated global drain signal: queued items, and
+                # the work in flight the carry reports.
+                held = in_flight(c)
+                total0 = lax.psum(q.size if held is None else q.size + held,
+                                  axes)
                 q, c, p, ctx, rounds, tele, _ = lax.while_loop(
                     cond, body,
                     (q, c, p0, ctx0, jnp.int32(0), tele0, total0))

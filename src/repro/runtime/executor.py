@@ -30,8 +30,16 @@ production hot path:
   back once, so autotuning never leaves the device and k rounds cost one
   dispatch + one host sync instead of k of each.  With
   ``until_drained=True`` the scan becomes a ``lax.while_loop`` that
-  stops on device the moment every lane is empty and reports the rounds
-  actually executed.
+  stops on device the moment every lane is empty and no worker body
+  holds work in flight, and reports the rounds actually executed.
+
+**Work in flight.**  A worker body whose carry holds items across rounds
+(popped, not yet finished) reports them as a ``(lanes,)`` int32 leaf
+named ``in_flight`` of a dict carry (:data:`IN_FLIGHT`).  Every drain
+test — the early-exit block's, the mesh block's and :meth:`StealRuntime.
+run`'s host check — ends a drain only when every queue is empty AND
+every lane's ``in_flight`` is zero.  A carry without the leaf traces to
+the queue-only test, so such bodies run the same programs as before.
 
 Worker bodies run *under vmap/shard_map* with the runtime's axis name in
 scope, so they may use collectives (e.g. ``lax.pmax`` for a global
@@ -66,7 +74,21 @@ Pytree = Any
 WorkerFn = Callable[[bulk_ops.QueueState, Pytree],
                     Tuple[bulk_ops.QueueState, Pytree]]
 
-__all__ = ["StealRuntime", "LiftedJit", "make_lane_step"]
+__all__ = ["IN_FLIGHT", "StealRuntime", "LiftedJit", "in_flight",
+           "make_lane_step"]
+
+# The carry leaf by which a worker body reports the items it holds
+# across rounds (module docstring, "Work in flight").
+IN_FLIGHT = "in_flight"
+
+
+def in_flight(carry: Pytree) -> Optional[jnp.ndarray]:
+    """The work in flight a worker body's carry reports (its
+    :data:`IN_FLIGHT` leaf, one count a lane), or None where the carry
+    reports none."""
+    if isinstance(carry, dict):
+        return carry.get(IN_FLIGHT)
+    return None
 
 
 class LiftedJit:
@@ -389,6 +411,13 @@ class StealRuntime:
 
     def total_size(self) -> int:
         return int(self.sizes().sum())
+
+    def _drained(self, carry: Pytree) -> bool:
+        """Every queue empty and no work in flight in ``carry``."""
+        if self.total_size() != 0:
+            return False
+        held = in_flight(carry)
+        return held is None or int(np.asarray(held).sum()) == 0
 
     # -- host-side seeding / draining ---------------------------------------
 
@@ -761,8 +790,9 @@ class StealRuntime:
         the adaptive proportion updated as a traced scalar inside the
         carry, telemetry stacked ``(k, ...)`` along the scan axis.  With
         ``until_drained`` the scan becomes a ``lax.while_loop`` over the
-        same round body that exits as soon as every lane is empty (checked
-        on device, before each round), writing telemetry into
+        same round body that exits as soon as every lane is empty and no
+        lane holds work in flight (checked on device, before each round),
+        writing telemetry into
         preallocated ``(k, ...)`` slots and returning the executed round
         count.  The round count, the final proportion and the telemetry
         leave the device packed in one int32 buffer
@@ -806,8 +836,15 @@ class StealRuntime:
                 lambda s: jnp.zeros((k,) + tuple(s.shape), s.dtype), tele_sds)
 
             def cond(state):
-                qs, _carry, _p, _ctx, r, _tele = state
-                return (r < k) & (jnp.sum(qs.size) > 0)
+                qs, carry, _p, _ctx, r, _tele = state
+                # In this order a carry without the leaf traces the
+                # program the queue-only test always traced.
+                more = r < k
+                pending = jnp.sum(qs.size)
+                held = in_flight(carry)
+                if held is not None:
+                    pending = pending + jnp.sum(held)
+                return more & (pending > 0)
 
             def body(state):
                 qs, carry, p, ctx, r, tele = state
@@ -946,9 +983,10 @@ class StealRuntime:
         ``lax.scan`` of exactly ``k`` rounds, returning
         ``(carry_out, stats)`` where ``stats`` leaves carry a leading
         ``(k,)`` round axis.  With ``until_drained=True`` the block is a
-        ``lax.while_loop`` that exits early once every lane is empty
-        (checked on device before each round — a drained workload costs
-        zero no-op rounds) and returns ``(carry_out, stats, rounds)``
+        ``lax.while_loop`` that exits early once every lane is empty and
+        the carry reports no work in flight (checked on device before
+        each round — a drained workload costs zero no-op rounds) and
+        returns ``(carry_out, stats, rounds)``
         where ``rounds <= k`` is the number actually executed and
         ``stats`` leaves carry a leading ``(rounds,)`` axis.
 
@@ -1047,7 +1085,8 @@ class StealRuntime:
             max_rounds: int = 10_000,
             stop_when_empty: bool = True,
             fused: int = 1) -> Pytree:
-        """Drive rounds until the queues drain (or ``max_rounds``).
+        """Drive rounds until the queues drain and the carry reports no
+        work in flight (or ``max_rounds``).
 
         With ``fused > 1`` the loop advances up to ``fused`` rounds per
         device dispatch (:meth:`run_fused`); when ``stop_when_empty`` the
@@ -1070,6 +1109,6 @@ class StealRuntime:
             else:
                 carry, _ = self.round(worker_fn, carry)
                 rounds += 1
-                if stop_when_empty and self.total_size() == 0:
+                if stop_when_empty and self._drained(carry):
                     break
         return carry

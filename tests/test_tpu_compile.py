@@ -69,6 +69,11 @@ MAIN_PATH = [
     ("gather", 16384, 6, 8192, I32),       # max_steal
     ("transfer", 16384, 6, 8192, I32),
     ("scatter", 16384, 6, 2048, I32),      # the root's 2,000 children
+    # fig9-pareto lanes: int32 node ids, 512 slots a lane.
+    ("slice", 16384, 1, 512, I32),         # worker pop of the free slots
+    ("scatter", 16384, 1, 2048, I32),      # worker push of 512 x 4 children
+    ("gather", 16384, 1, 8192, I32),       # max_steal
+    ("transfer", 16384, 1, 8192, I32),
     # DD solver items: pop 4, push batch x explore_width = 32.
     ("slice", 4096, 1, 4, I32),
     ("scatter", 4096, 1, 32, I32),
